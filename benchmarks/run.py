@@ -752,34 +752,50 @@ def _serve_sharded_measure() -> list:
     return rows
 
 
+def _rows_on_8_cpu_devices(module: str, flag: str, sentinel: str) -> list:
+    """JSON rows from ``python -m <module> <flag>`` under 8 virtual CPU
+    devices.
+
+    Forcing the flag in this process would re-partition its whole CPU and
+    distort every other family's timings, so a child gets the devices.
+    The respawn exists only for that: a parent that has touched JAX holds
+    an accelerator, and a child could never reach it — on any platform
+    but the CPU this stops with the reason instead.
+    """
+    import os
+    import pathlib
+    import subprocess
+
+    import jax
+    platform = jax.default_backend()
+    if platform != "cpu":
+        raise RuntimeError(
+            f"{module} {flag} needs 8 devices; this {platform} process "
+            f"sees {len(jax.devices())}, and a child process cannot reach "
+            "devices this process already holds")
+    env = dict(os.environ)
+    env["XLA_FLAGS"] = ("--xla_force_host_platform_device_count=8 "
+                        + env.get("XLA_FLAGS", "")).strip()
+    cmd = [sys.executable, "-m", module, flag] + (["--fast"] if FAST else [])
+    out = subprocess.run(
+        cmd, capture_output=True, text=True, timeout=1200, env=env,
+        cwd=str(pathlib.Path(__file__).resolve().parent.parent))
+    assert out.returncode == 0, out.stderr[-3000:]
+    return json.loads(out.stdout.split(sentinel + "\n", 1)[1])
+
+
 def serve_sharded():
     """Sharded continuous batching: 8 data shards vs 1 on one trace.
 
-    The measurement needs >= 8 devices; when the current process has
-    fewer (the usual single-device CPU run), it re-runs itself in a
-    subprocess with 8 virtual host devices — forcing the flag here would
-    re-partition the whole process's CPU and distort every other family's
-    timings.
+    The measurement needs >= 8 devices; a CPU process with fewer re-runs
+    it under 8 virtual host devices (:func:`_rows_on_8_cpu_devices`).
     """
     import jax
     if len(jax.devices()) >= 8:
         rows = _serve_sharded_measure()
     else:
-        import os
-        import pathlib
-        import subprocess
-        env = dict(os.environ)
-        env["XLA_FLAGS"] = ("--xla_force_host_platform_device_count=8 "
-                            + env.get("XLA_FLAGS", "")).strip()
-        cmd = [sys.executable, "-m", "benchmarks.run", "--sharded-child"]
-        if FAST:
-            cmd.append("--fast")
-        out = subprocess.run(
-            cmd, capture_output=True, text=True, timeout=1200, env=env,
-            cwd=str(pathlib.Path(__file__).resolve().parent.parent))
-        assert out.returncode == 0, out.stderr[-3000:]
-        payload = out.stdout.split("SHARDED_JSON\n", 1)[1]
-        rows = json.loads(payload)
+        rows = _rows_on_8_cpu_devices("benchmarks.run", "--sharded-child",
+                                      "SHARDED_JSON")
     for r in rows:
         emit(f"serve_sharded/fp32/dim={r['dim']}/shards={r['n_shards']}",
              r["makespan_s"] * 1e6 / r["total_steps"],
@@ -1188,24 +1204,8 @@ def serve_sustained():
     if len(jax.devices()) >= 8:
         rows.extend(sustained.measure_chaos(FAST))
     else:
-        # same respawn dance as serve_sharded: forcing 8 virtual devices
-        # in-process would re-partition the CPU under every other family
-        import os
-        import pathlib
-        import subprocess
-        env = dict(os.environ)
-        env["XLA_FLAGS"] = ("--xla_force_host_platform_device_count=8 "
-                            + env.get("XLA_FLAGS", "")).strip()
-        cmd = [sys.executable, "-m", "benchmarks.sustained",
-               "--chaos-child"]
-        if FAST:
-            cmd.append("--fast")
-        out = subprocess.run(
-            cmd, capture_output=True, text=True, timeout=1200, env=env,
-            cwd=str(pathlib.Path(__file__).resolve().parent.parent))
-        assert out.returncode == 0, out.stderr[-3000:]
-        payload = out.stdout.split("SUSTAINED_JSON\n", 1)[1]
-        rows.extend(json.loads(payload))
+        rows.extend(_rows_on_8_cpu_devices(
+            "benchmarks.sustained", "--chaos-child", "SUSTAINED_JSON"))
     gate = sustained.gates(rows)
     with open(SUSTAINED_OUT, "w") as fh:
         json.dump({"benchmark": "serve_sustained", "fast_mode": FAST,
@@ -1342,6 +1342,8 @@ def main(argv=None) -> None:
     args = ap.parse_args(argv)
     FAST = args.fast
     JSON_OUT = args.json_out
+    from repro.launch.cache import enable_compile_cache
+    enable_compile_cache()
     if args.sharded_child:
         # re-invoked by serve_sharded() under 8 virtual devices: measure,
         # dump rows after a sentinel, and exit before any CSV output

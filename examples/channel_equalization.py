@@ -17,6 +17,7 @@ sys.path.insert(0, "src")
 import jax.numpy as jnp
 import numpy as np
 
+from repro.launch.cache import enable_compile_cache
 from repro.core.esn import (ESNConfig, fit_readout, init_esn, predict,
                             run_reservoir)
 from repro.data.pipeline import channel_equalization
@@ -31,6 +32,7 @@ def ser(pred, target):
 
 
 def main():
+    enable_compile_cache()
     n = 6000
     u, d = channel_equalization(n, seed=0, snr_db=28.0)
     u = (u / np.abs(u).max()).astype(np.float32)

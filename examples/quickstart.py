@@ -16,6 +16,7 @@ sys.path.insert(0, "src")
 import jax.numpy as jnp
 import numpy as np
 
+from repro.launch.cache import enable_compile_cache
 from repro.core import baselines
 from repro.core.esn import (ESNConfig, fit_readout, init_esn, nrmse, predict,
                             run_readout, run_reservoir)
@@ -23,6 +24,7 @@ from repro.data.pipeline import mackey_glass
 
 
 def main():
+    enable_compile_cache()
     print("=== reservoir: fixed sparse matrix, compiled offline ===")
     cfg = ESNConfig(reservoir_dim=800, element_sparsity=0.75,  # [5] baseline
                     mode="int8-csd", seed=0)

@@ -30,12 +30,14 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.launch.cache import enable_compile_cache
 from repro.core.esn import ESNConfig, fit_readout, init_esn, run_reservoir
 from repro.serve import (AsyncReservoirServer, PaddingBucketer,
                          ReservoirEngine, ServeStats, SubmitSpec)
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--dim", type=int, default=256)
     ap.add_argument("--backend", default="auto",

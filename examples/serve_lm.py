@@ -24,6 +24,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.launch.cache import enable_compile_cache
 from repro.configs.base import ModelConfig
 from repro.launch.mesh import make_host_mesh
 from repro.launch.steps import make_decode_step, make_prefill_step
@@ -37,6 +38,7 @@ CFG = ModelConfig(
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--requests", type=int, default=8)
     ap.add_argument("--min-prompt", type=int, default=24)

@@ -31,6 +31,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.launch.cache import enable_compile_cache
 from repro import obs
 from repro.core.esn import ESNConfig, fit_readout, init_esn, run_reservoir
 from repro.serve import (AsyncReservoirServer, ReservoirEngine, ServeStats,
@@ -38,6 +39,7 @@ from repro.serve import (AsyncReservoirServer, ReservoirEngine, ServeStats,
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--dim", type=int, default=256)
     ap.add_argument("--backend", default="auto",

@@ -23,6 +23,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.launch.cache import enable_compile_cache
 from repro.core.esn import (ESNConfig, fit_readout, init_esn, predict,
                             run_reservoir)
 from repro.launch.report import plan_table
@@ -31,6 +32,7 @@ from repro.serve import (PaddingBucketer, ReservoirEngine, RolloutRequest,
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--dim", type=int, default=512)
     ap.add_argument("--mode", default="fp32",

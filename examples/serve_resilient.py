@@ -35,6 +35,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.launch.cache import enable_compile_cache
 from repro.core.esn import ESNConfig, fit_readout, init_esn, run_reservoir
 from repro.dist import DistributedReservoirServer, ShardedReservoirEngine
 from repro.runtime.elastic import AutoscalePolicy
@@ -153,6 +154,7 @@ def act_two_chaos(params, n_req):
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--dim", type=int, default=256)
     ap.add_argument("--requests", type=int, default=64)

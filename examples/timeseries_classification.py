@@ -17,6 +17,7 @@ sys.path.insert(0, "src")
 import jax.numpy as jnp
 import numpy as np
 
+from repro.launch.cache import enable_compile_cache
 from repro.core.esn import ESNConfig, init_esn, run_reservoir
 from repro.core.ridge import ridge_fit
 
@@ -42,6 +43,7 @@ def make_dataset(n_per_class=60, t=120, channels=4, seed=0):
 
 
 def main():
+    enable_compile_cache()
     x, y = make_dataset()
     split = 120
     cfg = ESNConfig(reservoir_dim=400, input_dim=4, element_sparsity=0.8,

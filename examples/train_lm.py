@@ -26,6 +26,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.launch.cache import enable_compile_cache
 from repro.checkpoint import store
 from repro.configs.base import ModelConfig
 from repro.data.pipeline import LMStreamConfig, lm_batch
@@ -48,6 +49,7 @@ PRESETS = {
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--preset", default="tiny", choices=sorted(PRESETS))
     ap.add_argument("--steps", type=int, default=60)

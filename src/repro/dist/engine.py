@@ -20,7 +20,7 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
+from jax.sharding import NamedSharding
 
 from repro.launch.mesh import make_data_mesh
 from repro.parallel.sharding import (batch_spec, data_axis_names,
@@ -38,7 +38,8 @@ class ShardedReservoirEngine(ReservoirEngine):
     ``run_segment`` chunk API) and the same compiled per-shard program;
     the only new behavior is batch padding up to a multiple of the shard
     count (padded rows are zero sequences riding along in otherwise-idle
-    shard capacity, and never leave the engine).
+    shard capacity, and never leave the engine).  Padding and trimming run
+    inside the sharded program, so no operand is staged on one device.
 
     Pass a ``mesh`` (any mesh with 'data' — and optionally 'pod' — axes)
     or just ``n_shards`` to build a 1-D data mesh over the first N local
@@ -46,7 +47,7 @@ class ShardedReservoirEngine(ReservoirEngine):
     """
 
     def __init__(self, params, *, mesh=None, n_shards: int | None = None,
-                 backend: str = "auto", interpret: bool = True,
+                 backend: str = "auto",
                  stats: ServeStats | None = None,
                  dense_dispatch_density: float = DENSE_DISPATCH_DENSITY,
                  vmem_budget: int | None = _UNSET,
@@ -58,7 +59,9 @@ class ShardedReservoirEngine(ReservoirEngine):
             f"mesh has no data axes: {self.mesh.axis_names}"
         self.n_shards = data_axis_size(self.mesh)
         self._batch_spec = batch_spec(self.mesh)
-        self.interpret = interpret
+        # where a pool's batch-major buffers live: rows split over the
+        # data shards, as the sharded rollout consumes them
+        self.batch_sharding = NamedSharding(self.mesh, self._batch_spec)
         # kept for elastic rebuilds: shrink() must reconstruct the engine
         # with the same dispatch policy, not the default
         self.dense_dispatch_density = dense_dispatch_density
@@ -66,8 +69,7 @@ class ShardedReservoirEngine(ReservoirEngine):
         # constructor — the per-shard program IS the single-device program
         # (shard_map wraps _local_rollout), so the sharded engine inherits
         # the tuned schedule for free.
-        super().__init__(params, backend=backend, interpret=interpret,
-                         stats=stats,
+        super().__init__(params, backend=backend, stats=stats,
                          dense_dispatch_density=dense_dispatch_density,
                          vmem_budget=vmem_budget, specialize=specialize,
                          tenant=tenant, crossover=crossover,
@@ -90,7 +92,6 @@ class ShardedReservoirEngine(ReservoirEngine):
             self.params if params is None else params,
             mesh=self.mesh if mesh is None else mesh,
             backend=self.backend if same else self.requested_backend,
-            interpret=self.interpret,
             stats=self.stats if stats is None else stats,
             dense_dispatch_density=self.dense_dispatch_density,
             vmem_budget=self.vmem_budget if same else _UNSET,
@@ -101,7 +102,8 @@ class ShardedReservoirEngine(ReservoirEngine):
 
     def _sharded(self, with_readout: bool, with_final: bool,
                  donate: bool = False):
-        """jit(shard_map(local_rollout)) cached per output signature.
+        """jit(pad -> shard_map(local_rollout) -> trim), cached per output
+        signature.
 
         The shard_map body is the *specialized* local rollout callable —
         the sharded path inherits whatever program the plan selected
@@ -112,38 +114,40 @@ class ShardedReservoirEngine(ReservoirEngine):
         key = (with_readout, with_final, donate)
         fn = self._sharded_fns.get(key)
         if fn is None:
-            spec = self._batch_spec
-            out_specs = (spec, spec) if with_final else spec
-            # check_rep=False: the weights/plan artifacts enter as closure
-            # constants (replicated), which the replication checker cannot
+            spec, n = self._batch_spec, self.n_shards
+            # check_vma=False: the weights/plan artifacts enter as closure
+            # constants (replicated), which the varying-axes checker cannot
             # see through on the pallas path.
-            fn = jax.jit(shard_map(
+            body = jax.shard_map(
                 self._local_rollout(with_readout, with_final),
-                mesh=self.mesh, in_specs=(spec, spec), out_specs=out_specs,
-                check_rep=False),
-                donate_argnums=(1,) if donate else ())
+                mesh=self.mesh, in_specs=(spec, spec),
+                out_specs=(spec, spec) if with_final else spec,
+                check_vma=False)
+
+            def run(u, x0b):
+                b = u.shape[0]
+                pad = -(-b // n) * n - b
+                if pad:
+                    u = jnp.pad(u, ((0, pad), (0, 0), (0, 0)))
+                    x0b = jnp.pad(x0b, ((0, pad), (0, 0)))
+                out = body(u, x0b)
+                if not pad:
+                    return out
+                if with_final:
+                    return out[0][:b], out[1][:b]
+                return out[:b]
+
+            fn = jax.jit(run, donate_argnums=(1,) if donate else ())
             self._sharded_fns[key] = fn
         return fn
 
     def _dispatch(self, u, x0b, with_readout: bool, with_final: bool,
                   donate: bool = False):
-        b = u.shape[0]
-        bpad = -(-b // self.n_shards) * self.n_shards
-        if bpad != b:
-            u = jnp.pad(u, ((0, bpad - b), (0, 0), (0, 0)))
-            x0b = jnp.pad(x0b, ((0, bpad - b), (0, 0)))
         fn = self._sharded(with_readout, with_final, donate)
         out = donated_call(fn, u, x0b) if donate else fn(u, x0b)
-        out, xf = out if with_final else (out, None)
-        if bpad != b:
-            out = out[:b]
-            xf = None if xf is None else xf[:b]
-        return out, xf
+        return out if with_final else (out, None)
 
-    def _record(self, out, batch, steps, t0, real_steps, defer=False):
-        # account the shard-padding rows as executed-but-padded work, so
-        # padding_efficiency stays honest about the sharding overhead
-        bpad = -(-batch // self.n_shards) * self.n_shards
-        if real_steps is None:
-            real_steps = batch * steps
-        return super()._record(out, bpad, steps, t0, real_steps, defer=defer)
+    def _executed_rows(self, batch: int) -> int:
+        # shard padding first, then each shard's own batch-tile padding
+        per_shard = -(-batch // self.n_shards)
+        return self.n_shards * super()._executed_rows(per_shard)

@@ -67,6 +67,15 @@ class ShardedContinuousBatcher(ContinuousBatcher):
                          resolver=resolver)
         self.shard_stats = [ServeStats() for _ in range(self.n_shards)]
 
+    def _place(self, x):
+        """Split a slot-major pool buffer over the engine's shards (slot
+        ``k`` on shard ``k // slots_per_shard``)."""
+        return jax.device_put(x, self.engine.batch_sharding)
+
+    def _constrain(self, x):
+        """Keep a jitted pool op's result on the engine's shards."""
+        return jax.lax.with_sharding_constraint(x, self.engine.batch_sharding)
+
     def shard_of(self, slot: int) -> int:
         return slot // self.slots_per_shard
 

@@ -7,7 +7,9 @@
   in VMEM across the scan, static BCSR + digit-plane culling, fp32 and
   exact-int8 modes (serving hot path behind ``repro.serve``)
 
-All kernels are written for TPU (pl.pallas_call + BlockSpec VMEM tiling)
-and validated with interpret=True on CPU against pure-jnp oracles.
+All kernels are written for TPU (pl.pallas_call + BlockSpec VMEM tiling).
+``platform.pallas_interpret`` derives how they run from the platform:
+compiled on the TPU, interpreted on the CPU, where the tests hold them to
+pure-jnp oracles.
 EXAMPLE.md documents the per-kernel layout convention.
 """

@@ -21,6 +21,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels.platform import pallas_interpret
+
 
 def _kernel(cols_ref, rows_ref, x_ref, blk_ref, o_ref):
     i = pl.program_id(0)
@@ -39,7 +41,7 @@ def _kernel(cols_ref, rows_ref, x_ref, blk_ref, o_ref):
 
 
 @functools.partial(jax.jit,
-                   static_argnames=("out_cols", "block", "interpret"))
+                   static_argnames=("out_cols", "block"))
 def bcsr_matmul(
     x: jnp.ndarray,
     blocks: jnp.ndarray,
@@ -48,7 +50,6 @@ def bcsr_matmul(
     out_cols: int,
     *,
     block: int = 128,
-    interpret: bool = True,
 ) -> jnp.ndarray:
     """Block-sparse product over a static structure.
 
@@ -82,5 +83,5 @@ def bcsr_matmul(
         _kernel,
         out_shape=jax.ShapeDtypeStruct((b, out_cols), out_dtype),
         grid_spec=grid_spec,
-        interpret=interpret,
+        interpret=pallas_interpret(),
     )(block_cols, block_rows, x, blocks)

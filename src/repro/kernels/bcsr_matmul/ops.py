@@ -19,8 +19,7 @@ class BcsrMatmul:
     """Precompiled block-sparse multiplier over one static tile layout."""
 
     def __init__(self,
-                 source: FixedMatrix | ExecutionPlan | BlockSparse | BcsrLayout,
-                 interpret: bool = True):
+                 source: FixedMatrix | ExecutionPlan | BlockSparse | BcsrLayout):
         if isinstance(source, BcsrLayout):
             layout = source
         elif isinstance(source, ExecutionPlan):
@@ -30,7 +29,6 @@ class BcsrMatmul:
         else:
             layout = BcsrLayout.from_blocks(source)
         self.layout = layout
-        self.interpret = interpret
 
     # Everything static lives on the layout; expose the public surface
     # as read-only views instead of mirrored copies.
@@ -49,5 +47,5 @@ class BcsrMatmul:
         if r != self.rows_pad:
             x = jnp.pad(x, ((0, 0), (0, self.rows_pad - r)))
         y = bcsr_matmul(x, self.data, self.cols, self.rows, self.cols_pad,
-                        block=self.block, interpret=self.interpret)
+                        block=self.block)
         return y[:, : self.shape[1]]

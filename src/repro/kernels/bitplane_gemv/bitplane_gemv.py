@@ -24,6 +24,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels.platform import pallas_interpret
+
 DEFAULT_BLOCK_R = 128
 DEFAULT_BLOCK_C = 128
 
@@ -50,8 +52,7 @@ def _kernel(x_ref, dig_ref, o_ref, *, width: int, plane_mask: tuple):
     o_ref[...] += acc
 
 
-@functools.partial(jax.jit, static_argnames=("block_r", "block_c", "plane_mask",
-                                             "interpret"))
+@functools.partial(jax.jit, static_argnames=("block_r", "block_c", "plane_mask"))
 def bitplane_gemv(
     x: jnp.ndarray,
     digits: jnp.ndarray,
@@ -59,7 +60,6 @@ def bitplane_gemv(
     block_r: int = DEFAULT_BLOCK_R,
     block_c: int = DEFAULT_BLOCK_C,
     plane_mask: tuple | None = None,
-    interpret: bool = True,
 ) -> jnp.ndarray:
     """``y[b, c] = sum_r x[b, r] * V[r, c]`` via digit planes.
 
@@ -67,8 +67,6 @@ def bitplane_gemv(
         x: (B, R) int8/int32 activations (R divisible by block_r).
         digits: (W, R, C) int8 planes in {-1, 0, 1} with V = sum 2^w digits[w].
         plane_mask: per-plane keep flags (None keeps all planes).
-        interpret: run the Pallas interpreter (CPU container); on real TPU
-            pass False.
 
     Returns:
         (B, C) int32 exact integer product.
@@ -90,5 +88,5 @@ def bitplane_gemv(
             pl.BlockSpec((w, block_r, block_c), lambda ci, ri: (0, ri, ci)),
         ],
         out_specs=pl.BlockSpec((b, block_c), lambda ci, ri: (0, ci)),
-        interpret=interpret,
+        interpret=pallas_interpret(),
     )(x, digits)

@@ -29,14 +29,12 @@ class BitplaneGemv:
     """
 
     def __init__(self, source: FixedMatrix | ExecutionPlan,
-                 block_r: int = 128, block_c: int = 128,
-                 interpret: bool = True):
+                 block_r: int = 128, block_c: int = 128):
         plan = source if isinstance(source, ExecutionPlan) else plan_for(source)
         self.plan = plan
         self.digits = plan.padded_digits(block_r, block_c)
         self.rows, self.cols = plan.shape
         self.block_r, self.block_c = block_r, block_c
-        self.interpret = interpret
         # Whole-plane culling: CSD often leaves high planes empty.
         self.plane_mask = plan.plane_mask
 
@@ -49,6 +47,5 @@ class BitplaneGemv:
             x = jnp.pad(x, ((0, 0), (0, pad)))
         y = bitplane_gemv(x, self.digits,
                           block_r=self.block_r, block_c=self.block_c,
-                          plane_mask=self.plane_mask,
-                          interpret=self.interpret)
+                          plane_mask=self.plane_mask)
         return y[:, : self.cols]

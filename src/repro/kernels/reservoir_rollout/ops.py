@@ -34,7 +34,7 @@ class FusedRollout:
 
     def __init__(self, source: FixedMatrix | ExecutionPlan, w_in, *,
                  leak: float = 1.0, mode: str = "fp32", state_bits: int = 8,
-                 interpret: bool = True, w_out=None,
+                 w_out=None,
                  vmem_budget: int | None = DEFAULT_VMEM_BUDGET,
                  readout_every: int = 1):
         plan = source if isinstance(source, ExecutionPlan) else plan_for(source)
@@ -48,7 +48,6 @@ class FusedRollout:
         self.rpad = plan.cols_pad
         self.leak = float(leak)
         self.mode = mode
-        self.interpret = interpret
         self.readout_every = int(readout_every)
         self.smax = (1 << (state_bits - 1)) - 1
         self.recur_scale = plan.scale / self.smax
@@ -93,7 +92,7 @@ class FusedRollout:
             block=self.block, mode=self.mode, smax=self.smax,
             recur_scale=self.recur_scale, readout_every=self.readout_every,
             want_states=want_states, want_preds=want_preds,
-            want_final=want_final, interpret=self.interpret)
+            want_final=want_final)
         parts = list(out) if isinstance(out, tuple) else [out]
         trimmed = []
         if want_states:

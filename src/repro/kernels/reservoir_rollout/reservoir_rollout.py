@@ -21,7 +21,8 @@ synthesis-time adder culling.  Two modes share one kernel body:
 * ``fp32``  — dequantized tiles, bit-compatible with
   ``BlockSparse.matmul_ref`` accumulation order (shift is 0 and unused).
 * ``int8``  — exact digit-plane arithmetic: the state batch is requantized
-  every step and each term is a shifted int32 plane-tile dot.
+  to int8 every step and each term is a shifted int8 x int8 plane-tile
+  dot accumulated in int32.
 
 The optional fused readout applies ``W_out`` to the new state inside the
 launch (at every step, or every ``readout_every`` steps), so serving can
@@ -36,6 +37,22 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from repro.kernels.platform import pallas_interpret
+
+
+def input_projection(u, w_in):
+    """``u @ w_in`` for an int8-mode kernel, whose recurrence is exact.
+
+    A pre-activation one ulp off the reference's can flip a requantized
+    state digit, which the recurrence carries on and the readout
+    multiplies.  A one-deep product is therefore one f32 multiply on the
+    VPU, as exact as the reference's; the MXU, even at HIGHEST, rounds
+    differently.  Deeper products run on the MXU at HIGHEST.
+    """
+    if u.shape[1] == 1:
+        return u * w_in
+    return jnp.dot(u, w_in, precision=jax.lax.Precision.HIGHEST)
 
 
 def _rollout_kernel(*refs, band_plans, leak, block, mode, smax, recur_scale,
@@ -61,8 +78,12 @@ def _rollout_kernel(*refs, band_plans, leak, block, mode, smax, recur_scale,
     x = x_ref[...]
     u = u_ref[0]
     if mode == "int8":
-        # Per-step state requantization, exactly as esn._step_int8 does it.
-        xq = jnp.clip(jnp.round(x * smax), -smax - 1, smax).astype(jnp.int32)
+        # Per-step state requantization, exactly as esn._step_int8 does it
+        # (state_bits <= 8, so the quantized state is int8).
+        xq = jnp.clip(jnp.round(x * smax), -smax - 1, smax).astype(jnp.int8)
+    # int8 mode: the readout runs at HIGHEST (Mosaic's default rounds f32
+    # operands to bfloat16); fp32 mode keeps the default (ROADMAP 1.4)
+    prec = jax.lax.Precision.HIGHEST if mode == "int8" else None
     b = x.shape[0]
 
     for bi, cols in enumerate(band_plans):
@@ -84,10 +105,11 @@ def _rollout_kernel(*refs, band_plans, leak, block, mode, smax, recur_scale,
                     acc = jnp.zeros((b, block), jnp.int32)
                     for slot, shift, ri in terms:
                         xs = xq[:, ri * block:(ri + 1) * block]
-                        acc = acc + (
-                            (xs @ w_ref[0, slot].astype(jnp.int32)) << shift)
+                        acc = acc + (jax.lax.dot(
+                            xs, w_ref[0, slot],
+                            preferred_element_type=jnp.int32) << shift)
                     recur = acc.astype(jnp.float32) * recur_scale
-                    pre = u @ win_ref[:, sl] + recur
+                    pre = input_projection(u, win_ref[:, sl]) + recur
                 nx_ref[:, sl] = (1.0 - leak) * x[:, sl] + leak * jnp.tanh(pre)
 
     @pl.when(k == n_bands - 1)
@@ -104,16 +126,16 @@ def _rollout_kernel(*refs, band_plans, leak, block, mode, smax, recur_scale,
                 f_ref[...] = nx
         if want_preds:
             if readout_every == 1:
-                y_ref[0] = nx @ wout_ref[...]
+                y_ref[0] = jnp.dot(nx, wout_ref[...], precision=prec)
             else:
                 @pl.when((t + 1) % readout_every == 0)
                 def _emit_readout():
-                    y_ref[0] = nx @ wout_ref[...]
+                    y_ref[0] = jnp.dot(nx, wout_ref[...], precision=prec)
 
 
 @functools.partial(jax.jit, static_argnames=(
     "band_plans", "leak", "block", "mode", "smax", "recur_scale",
-    "readout_every", "want_states", "want_preds", "want_final", "interpret"))
+    "readout_every", "want_states", "want_preds", "want_final"))
 def reservoir_rollout(
     u_seq: jnp.ndarray,
     w_data: jnp.ndarray,
@@ -131,7 +153,6 @@ def reservoir_rollout(
     want_states: bool = True,
     want_preds: bool = False,
     want_final: bool = False,
-    interpret: bool = True,
 ):
     """Fused T-step rollout for a state batch, optionally banded + readout.
 
@@ -216,6 +237,6 @@ def reservoir_rollout(
         out_specs=out_specs[0] if single else tuple(out_specs),
         scratch_shapes=[pltpu.VMEM((b, r), jnp.float32),           # state
                         pltpu.VMEM((b, r), jnp.float32)],          # next state
-        interpret=interpret,
+        interpret=pallas_interpret(),
     )(*operands)
     return out

@@ -33,7 +33,10 @@ quantized block — one int32 MXU pass instead of ``width`` shifted plane
 passes) and ``SA`` terms unroll a sparse plane's few set digits as static
 shift-adds.  int8 terms accumulate in exact int32, so any schedule is
 bit-identical to the generic kernel; fp32 terms keep its ascending-row
-order for the same guarantee.
+order for the same guarantee.  The MXU takes the int8 state and int8
+tiles as they are, accumulating in int32; shift-add digits are added in
+place into their output lanes of an int32 scratch (Mosaic has no
+scatter).
 """
 
 from __future__ import annotations
@@ -49,10 +52,32 @@ from jax.experimental.pallas import tpu as pltpu
 
 from repro import obs
 from repro.core.sparse import FixedMatrix
+from repro.kernels.platform import pallas_interpret
+from repro.kernels.reservoir_rollout.reservoir_rollout import input_projection
 from repro.plan import (DEFAULT_BATCH_TILE, DEFAULT_VMEM_BUDGET,
                         ExecutionPlan, plan_for, specialize_rollout)
 from repro.plan.plan import pad_axis
-from repro.plan.specialize import MM
+from repro.plan.specialize import MM, SA
+
+
+def _shift_add(acc_ref, xi, base, digits):
+    """Add one SA term's digits into the (b, block) int32 ``acc_ref``.
+
+    Each output lane ``j`` sums its digits' ``±(x[:, base + i] << w)`` into
+    one (b, 1) column and adds it to that lane in place — the integers the
+    scatter ``acc[:, j] += ...`` adds, without a scatter (Mosaic has none),
+    in an order that cannot change them (int32 addition is exact).
+    """
+    by_lane: dict = {}
+    for i, j, s, w in digits:
+        by_lane.setdefault(j, []).append((i, s, w))
+    for j, srcs in sorted(by_lane.items()):
+        col = None
+        for i, s, w in srcs:
+            c = xi[:, base + i:base + i + 1] << w
+            c = c if s > 0 else -c
+            col = c if col is None else col + c
+        acc_ref[:, j:j + 1] += col
 
 
 def _specialized_kernel(*refs, schedules, leak, block, mode, smax,
@@ -66,7 +91,7 @@ def _specialized_kernel(*refs, schedules, leak, block, mode, smax,
     o_ref = rest.pop(0) if want_states else None
     y_ref = rest.pop(0) if want_preds else None
     f_ref = rest.pop(0) if want_final else None
-    x_ref, nx_ref = rest
+    x_ref, nx_ref, *sa_ref = rest        # + int32 SA accumulator if any
 
     t = pl.program_id(0)
     if n_bands > 1:
@@ -94,8 +119,14 @@ def _specialized_kernel(*refs, schedules, leak, block, mode, smax,
     x = x_ref[bsl, :]
     u = u_ref[0]
     if mode == "int8":
-        # per-step state requantization, exactly as the generic kernel
-        xq = jnp.clip(jnp.round(x * smax), -smax - 1, smax).astype(jnp.int32)
+        # per-step state requantization, exactly as the generic kernel;
+        # the engine admits state_bits <= 8 only, so the state is int8
+        xq = jnp.clip(jnp.round(x * smax), -smax - 1, smax).astype(jnp.int8)
+        if sa_ref:
+            xi = xq.astype(jnp.int32)
+    # int8 mode: the readout runs at HIGHEST (Mosaic's default rounds f32
+    # operands to bfloat16); fp32 mode keeps the default (ROADMAP 1.4)
+    prec = jax.lax.Precision.HIGHEST if mode == "int8" else None
 
     def run_band(cols):
         for ci, terms in cols:
@@ -113,19 +144,20 @@ def _specialized_kernel(*refs, schedules, leak, block, mode, smax,
             else:
                 # exact int32 accumulation: folded tiles + shift-add digits
                 acc = jnp.zeros((b_tile, block), jnp.int32)
-                for term in terms:
-                    if term[0] == MM:
-                        _tag, slot, shift, ri = term
-                        xs = xq[:, ri * block:(ri + 1) * block]
-                        acc = acc + (
-                            (xs @ w_ref[0, slot].astype(jnp.int32)) << shift)
-                    else:
-                        _tag, ri, digits = term
-                        for i, j, s, w in digits:
-                            col = xq[:, ri * block + i] << w
-                            acc = acc.at[:, j].add(col if s > 0 else -col)
+                for _tag, slot, shift, ri in (
+                        term for term in terms if term[0] == MM):
+                    xs = xq[:, ri * block:(ri + 1) * block]
+                    acc = acc + (jax.lax.dot(
+                        xs, w_ref[0, slot],
+                        preferred_element_type=jnp.int32) << shift)
+                sa_terms = [term for term in terms if term[0] == SA]
+                if sa_terms:
+                    sa_ref[0][...] = acc
+                    for _tag, ri, digits in sa_terms:
+                        _shift_add(sa_ref[0], xi, ri * block, digits)
+                    acc = sa_ref[0][...]
                 recur = acc.astype(jnp.float32) * recur_scale
-                pre = u @ win_ref[:, sl] + recur
+                pre = input_projection(u, win_ref[:, sl]) + recur
             nx_ref[nsl, sl] = (1.0 - leak) * x[:, sl] + leak * jnp.tanh(pre)
 
     def commit():
@@ -139,11 +171,11 @@ def _specialized_kernel(*refs, schedules, leak, block, mode, smax,
                 f_ref[...] = nx
         if want_preds:
             if readout_every == 1:
-                y_ref[0] = nx @ wout_ref[...]
+                y_ref[0] = jnp.dot(nx, wout_ref[...], precision=prec)
             else:
                 @pl.when((t + 1) % readout_every == 0)
                 def _emit_readout():
-                    y_ref[0] = nx @ wout_ref[...]
+                    y_ref[0] = jnp.dot(nx, wout_ref[...], precision=prec)
 
     if k is None:
         run_band(schedules[0])
@@ -177,7 +209,6 @@ def specialized_rollout(
     want_states: bool = True,
     want_preds: bool = False,
     want_final: bool = False,
-    interpret: bool = True,
 ):
     """Launch one program-specialized rollout (see module docstring).
 
@@ -249,6 +280,15 @@ def specialized_rollout(
         out_specs.append(pl.BlockSpec(
             (b_tile, r), im(lambda ti, bi, ki: (bi, 0))))
 
+    scratch = [pltpu.VMEM((b_pad, r), jnp.float32),              # state
+               # next state: one tile suffices when reduce and commit
+               # share a grid step (resident regime)
+               pltpu.VMEM((b_tile if n_bands == 1 else b_pad, r),
+                          jnp.float32)]
+    if any(term[0] == SA for cols in schedules for _ci, terms in cols
+           for term in terms):
+        # one column block's int32 sum, where shift-add digits land
+        scratch.append(pltpu.VMEM((b_tile, block), jnp.int32))
     single = len(out_shapes) == 1
     return pl.pallas_call(
         kernel,
@@ -256,12 +296,8 @@ def specialized_rollout(
         grid=grid,
         in_specs=in_specs,
         out_specs=out_specs[0] if single else tuple(out_specs),
-        scratch_shapes=[pltpu.VMEM((b_pad, r), jnp.float32),     # state
-                        # next state: one tile suffices when reduce and
-                        # commit share a grid step (resident regime)
-                        pltpu.VMEM((b_tile if n_bands == 1 else b_pad, r),
-                                   jnp.float32)],
-        interpret=interpret,
+        scratch_shapes=scratch,
+        interpret=pallas_interpret(),
     )(*operands)
 
 
@@ -279,7 +315,7 @@ class SpecializedRollout:
 
     def __init__(self, source: FixedMatrix | ExecutionPlan, w_in, *,
                  leak: float = 1.0, mode: str = "fp32", state_bits: int = 8,
-                 interpret: bool = True, w_out=None,
+                 w_out=None,
                  vmem_budget: int | None = DEFAULT_VMEM_BUDGET,
                  readout_every: int = 1,
                  batch_tile_max: int = DEFAULT_BATCH_TILE,
@@ -297,7 +333,6 @@ class SpecializedRollout:
         self.rpad = plan.cols_pad
         self.leak = float(leak)
         self.mode = mode
-        self.interpret = interpret
         self.readout_every = int(readout_every)
         self.smax = (1 << (state_bits - 1)) - 1
         self.recur_scale = plan.scale / self.smax
@@ -362,7 +397,7 @@ class SpecializedRollout:
                     recur_scale=me.recur_scale, b_tile=b_tile,
                     readout_every=me.readout_every,
                     want_states=want_states, want_preds=want_preds,
-                    want_final=want_final, interpret=me.interpret)
+                    want_final=want_final)
                 parts = list(out) if isinstance(out, tuple) else [out]
                 trimmed = []
                 if want_states:

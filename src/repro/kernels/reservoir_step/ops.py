@@ -22,11 +22,10 @@ class FusedReservoir:
     """Run a whole input sequence through the fused Pallas step via scan."""
 
     def __init__(self, w: np.ndarray, w_in: np.ndarray, leak: float = 1.0,
-                 block: int = 128, interpret: bool = True):
+                 block: int = 128):
         self.dim = w.shape[0]
         self.block = block
         self.leak = float(leak)
-        self.interpret = interpret
         wp = _pad_dim(_pad_dim(jnp.asarray(w, jnp.float32), 0, block), 1, block)
         self.w = wp
         self.w_in = _pad_dim(jnp.asarray(w_in, jnp.float32), 1, block)
@@ -35,8 +34,7 @@ class FusedReservoir:
         """x: (B, dim), u: (B, I) -> (B, dim)."""
         xp = _pad_dim(x, 1, self.block)
         nxt = reservoir_step(xp, self.w, u, self.w_in, leak=self.leak,
-                             block_r=self.block, block_c=self.block,
-                             interpret=self.interpret)
+                             block_r=self.block, block_c=self.block)
         return nxt[:, : self.dim]
 
     def run(self, inputs: jnp.ndarray, x0: jnp.ndarray | None = None

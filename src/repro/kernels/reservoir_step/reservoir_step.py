@@ -22,6 +22,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels.platform import pallas_interpret
+
 
 def _kernel(x_ref, w_ref, u_ref, win_ref, xold_ref, o_ref, *, leak: float,
             n_r: int):
@@ -39,8 +41,7 @@ def _kernel(x_ref, w_ref, u_ref, win_ref, xold_ref, o_ref, *, leak: float,
                       + leak * jnp.tanh(o_ref[...]))
 
 
-@functools.partial(jax.jit, static_argnames=("block_r", "block_c", "leak",
-                                             "interpret"))
+@functools.partial(jax.jit, static_argnames=("block_r", "block_c", "leak"))
 def reservoir_step(
     x: jnp.ndarray,
     w: jnp.ndarray,
@@ -50,7 +51,6 @@ def reservoir_step(
     leak: float = 1.0,
     block_r: int = 128,
     block_c: int = 128,
-    interpret: bool = True,
 ) -> jnp.ndarray:
     """Fused ESN update for a state batch.
 
@@ -81,5 +81,5 @@ def reservoir_step(
             pl.BlockSpec((b, block_c), lambda ci, ri: (0, ci)),       # x (old)
         ],
         out_specs=pl.BlockSpec((b, block_c), lambda ci, ri: (0, ci)),
-        interpret=interpret,
+        interpret=pallas_interpret(),
     )(x, w, u, w_in, x)

@@ -27,7 +27,8 @@ re-fetched.  This module is the software synthesis step that buys the
   synthesized adder trees.
 * **batch tiling** — the batch axis splits into tiles of at most
   ``batch_tile_max`` rows, so a batch-64 rollout runs as grid-parallel
-  batch tiles instead of one monolithic VMEM block.
+  batch tiles instead of one monolithic VMEM block.  A tile is a multiple
+  of the TPU's 8-row sublane tiling, or the whole batch.
 
 Every schedule is arithmetic-order-safe: int8 terms accumulate in exact
 int32 (any order gives the same bits) and fp32 terms keep the banded
@@ -65,6 +66,10 @@ SA = 1
 # VMEM bank even at dim 4096 (16 * 4096 * 4 B = 256 KiB), and batch 64
 # runs as four grid-parallel tiles instead of one monolithic block.
 DEFAULT_BATCH_TILE = 16
+
+# Rows of one (8, 128) vreg tile: a batch tile that is not the whole batch
+# must be a multiple of it, or Mosaic refuses the (1, b_tile, I) block.
+SUBLANES = 8
 
 
 def default_crossover(block: int) -> int:
@@ -111,11 +116,17 @@ class RolloutProgram:
     def batch_tiling(self, batch: int) -> tuple[int, int, int]:
         """(b_tile, n_tiles, b_padded) for a batch of ``batch`` rows.
 
-        Tiles are balanced (``ceil(B / n_tiles)`` rows each) so padding
-        never exceeds ``n_tiles - 1`` rows.
+        A batch that fits one tile is one tile of exactly ``batch`` rows.
+        Otherwise the tile is the multiple of :data:`SUBLANES` (at most
+        ``max(batch_tile_max, SUBLANES)``) that pads the batch least,
+        the larger tile on a tie; padding stays below one tile.
         """
-        n_tiles = max(1, -(-batch // self.batch_tile_max))
-        b_tile = -(-batch // n_tiles)
+        cap = max(self.batch_tile_max, SUBLANES)
+        if batch <= cap:
+            return batch, 1, batch
+        b_tile = min(range(SUBLANES, cap + 1, SUBLANES),
+                     key=lambda bt: (-(-batch // bt) * bt, -bt))
+        n_tiles = -(-batch // b_tile)
         return b_tile, n_tiles, b_tile * n_tiles
 
     def describe(self) -> str:
@@ -371,9 +382,9 @@ def int8_recur_reference(program: RolloutProgram, xq: jnp.ndarray,
                          rows_pad: int, out_cols: int) -> jnp.ndarray:
     """Schedule-driven exact integer recurrent product (XLA consumer).
 
-    ``xq``: (..., rows) int32 quantized states -> (..., out_cols) int32 —
-    bit-identical to ``FixedMatrix.matvec_int_exact`` because every term
-    accumulates in exact int32.  The same schedule the Pallas kernel
+    ``xq``: (..., rows) quantized states within int8 range -> (...,
+    out_cols) int32 — bit-identical to ``FixedMatrix.matvec_int_exact``
+    because every term accumulates in exact int32.  The same schedule the Pallas kernel
     unrolls, expressed in plain jnp for the XLA backend (and for parity
     tests).
     """
@@ -388,10 +399,10 @@ def int8_recur_reference(program: RolloutProgram, xq: jnp.ndarray,
             for term in terms:
                 if term[0] == MM:
                     _tag, slot, shift, ri = term
-                    xs = xp[..., ri * bk:(ri + 1) * bk]
-                    acc = acc + (
-                        (xs @ program.data[bi, slot].astype(jnp.int32))
-                        << shift)
+                    xs = xp[..., ri * bk:(ri + 1) * bk].astype(jnp.int8)
+                    acc = acc + (jnp.matmul(
+                        xs, program.data[bi, slot],
+                        preferred_element_type=jnp.int32) << shift)
                 else:
                     _tag, ri, digits = term
                     for i, j, s, w in digits:

@@ -13,8 +13,9 @@ compile-to-bitstream step) and fronts two fused implementations:
   (B*T, I) x (I, R) gemm before the scan.  The fast path on CPU/GPU.
 * ``pallas`` — the ``reservoir_rollout`` Pallas kernel fed by the plan's
   VMEM-banded layout: T steps fused in one launch, state resident in VMEM,
-  one band of weight tiles streamed per grid step.  The TPU path
-  (``interpret=True`` elsewhere).
+  one band of weight tiles streamed per grid step.  The TPU path: compiled
+  on the TPU, run by the Pallas interpreter on the CPU — the platform
+  decides (:mod:`repro.kernels.platform`), never a caller option.
 
 With a trained readout the engine serves *predictions*: ``W_out`` is fused
 into the rollout epilogue (per-step ``y = x @ W_out`` inside the scan body
@@ -80,7 +81,7 @@ class ReservoirEngine:
     """Fused batched rollout (and readout) for one frozen ESN."""
 
     def __init__(self, params: ESNParams, *, backend: str = "auto",
-                 interpret: bool = True, stats: ServeStats | None = None,
+                 stats: ServeStats | None = None,
                  dense_dispatch_density: float = DENSE_DISPATCH_DENSITY,
                  vmem_budget: int | None = _UNSET,
                  specialize: bool = True, tenant: str | None = None,
@@ -96,6 +97,13 @@ class ReservoirEngine:
         self.plan = plan_for(params.w, tenant=tenant)
         self.specialize = specialize
         self._int8 = self.config.mode.startswith("int8")
+        if self._int8 and self.config.state_bits > 8:
+            # every int8 recurrence multiplies the requantized state as an
+            # int8 operand; a wider state would be silently clipped
+            raise ValueError(
+                f"mode {self.config.mode!r} takes state_bits <= 8 (the "
+                f"requantized state is an int8 operand), got "
+                f"state_bits={self.config.state_bits}")
         # backend="auto" resolves through the plan autotuner: a persisted
         # tuning cache replays the measured winner, a cold cache falls
         # back to the analytic cost model's pick — never a hardcoded
@@ -155,7 +163,7 @@ class ReservoirEngine:
             self._fused = cls(
                 self.plan, params.w_in, leak=self.config.leak,
                 mode="int8" if self._int8 else "fp32",
-                state_bits=self.config.state_bits, interpret=interpret,
+                state_bits=self.config.state_bits,
                 w_out=self._w_out, vmem_budget=self.vmem_budget, **kw)
         else:
             # jitted rollouts keyed on (with_readout, with_final, donated);
@@ -196,7 +204,7 @@ class ReservoirEngine:
             # the width shifted pos/neg plane products, bit-identically
             # (int32 accumulation is exact).  Block-sparse ones run the
             # program's culled folded/shift-add schedule.
-            q_folded = w.q.astype(jnp.int32) if self._int8_dense else None
+            q_folded = w.q if self._int8_dense else None
             program = None
             if int8 and self.specialize and not self._int8_dense:
                 program = specialize_rollout(
@@ -225,9 +233,10 @@ class ReservoirEngine:
             def body(x, up):
                 if int8:
                     xq = jnp.clip(jnp.round(x * smax), -smax - 1,
-                                  smax).astype(jnp.int32)
+                                  smax).astype(jnp.int8)
                     if q_folded is not None:
-                        ri = xq @ q_folded
+                        ri = jnp.matmul(xq, q_folded,
+                                        preferred_element_type=jnp.int32)
                     elif program is not None:
                         ri = int8_recur_reference(
                             program, xq, plan.rows_pad, dim)
@@ -343,6 +352,12 @@ class ReservoirEngine:
                 x0b = jnp.broadcast_to(x0b, (b, dim))
         return u, x0b, single
 
+    def _executed_rows(self, batch: int) -> int:
+        """Rows one rollout of ``batch`` sequences really runs: the
+        specialized kernel pads the batch to whole batch tiles."""
+        prog = self.program
+        return batch if prog is None else prog.batch_tiling(batch)[2]
+
     def _record(self, out, batch, steps, t0, real_steps, defer=False):
         # Under an outer jit/vmap/grad trace the inputs are tracers: still
         # composable (the jitted fn nests), but timing/stats are meaningless
@@ -356,9 +371,13 @@ class ReservoirEngine:
             # flagged in the stats and throughput should be read from the
             # scheduler's makespan clock, not ServeStats.seconds.
             seconds = time.perf_counter() - t0
-            self.stats.record_call(batch=batch, steps=steps,
-                                   seconds=seconds,
-                                   real_steps=real_steps, deferred=defer)
+            # padded rows count as executed-but-padded work, so
+            # padding_efficiency stays honest about batch-tile padding
+            self.stats.record_call(
+                batch=self._executed_rows(batch), steps=steps,
+                seconds=seconds, deferred=defer,
+                real_steps=batch * steps if real_steps is None
+                else real_steps)
             # deferred calls timed dispatch only; synced calls include the
             # device wait — two different span names so the trace never
             # conflates the two measurements.

@@ -177,21 +177,23 @@ class ContinuousBatcher:
         self._slots: list[QueuedRequest | None] = [None] * n_slots
         self._pos = [0] * n_slots               # steps consumed per slot
         self._chunks: list[list] = [[] for _ in range(n_slots)]
-        self._states = jnp.zeros((n_slots, self._dim), jnp.float32)
+        self._states = self._place(
+            jnp.zeros((n_slots, self._dim), jnp.float32))
         self._max_chunks = 4                    # input lanes; doubles on
         #                                         demand (longer requests)
         if zero_copy:
-            self._u_dev = jnp.zeros(
+            self._u_dev = self._place(jnp.zeros(
                 (n_slots, self._max_chunks, chunk_steps, self._in_dim),
-                jnp.float32)
+                jnp.float32))
             self._gather = jax.jit(
-                lambda u_dev, idx:
-                u_dev[jnp.arange(u_dev.shape[0]), idx])
+                lambda u_dev, idx: self._constrain(
+                    u_dev[jnp.arange(u_dev.shape[0]), idx]))
             # donated in-place lane write: admission cost stays O(request)
             # on accelerators instead of copying the whole pooled buffer
             self._lane_set = jax.jit(
-                lambda buf, slot, lanes: jax.lax.dynamic_update_slice(
-                    buf, lanes[None], (slot, 0, 0, 0)),
+                lambda buf, slot, lanes: self._constrain(
+                    jax.lax.dynamic_update_slice(
+                        buf, lanes[None], (slot, 0, 0, 0))),
                 donate_argnums=(0,))
         self.last_take: dict = {}               # slot -> steps, last chunk
         self.last_retired_slots: list = []
@@ -211,6 +213,16 @@ class ContinuousBatcher:
     def return_states(self) -> bool:
         """Deprecated alias of ``want_states`` (kept one release)."""
         return self.want_states
+
+    def _place(self, x):
+        """Put a slot-major pool buffer where the engine computes on it
+        (the default device; a sharded pool overrides this)."""
+        return jnp.asarray(x)
+
+    def _constrain(self, x):
+        """In-program twin of :meth:`_place` for a jitted pool op's
+        result."""
+        return x
 
     def _want_of(self, qreq: QueuedRequest) -> bool:
         return (self.want_states if qreq.want_states is None
@@ -328,10 +340,10 @@ class ContinuousBatcher:
                 while n_chunks > self._max_chunks:
                     self._max_chunks *= 2
                 # one reallocation straight to the final lane count
-                self._u_dev = jnp.zeros(
+                self._u_dev = self._place(jnp.zeros(
                     (self.n_slots, self._max_chunks, cs, self._in_dim),
                     jnp.float32).at[:, : self._u_dev.shape[1]].set(
-                        self._u_dev)
+                        self._u_dev))
             padded = np.zeros((self._max_chunks * cs,) + seg.shape[1:],
                               np.float32)
             padded[: seg.shape[0]] = seg
@@ -391,7 +403,7 @@ class ContinuousBatcher:
                     np.float32)
                 u_host[i, :len(seg)] = seg
                 take[i] = len(seg)
-            u = jnp.asarray(u_host)
+            u = self._place(u_host)
         # group occupied slots by pinned (engine, contract); slot order
         # inside and across groups is deterministic (dict insertion
         # follows slot index)

@@ -1,4 +1,4 @@
-"""Pallas kernels vs pure-jnp oracles (interpret=True on CPU).
+"""Pallas kernels vs pure-jnp oracles (interpreted on the CPU).
 
 Each kernel sweeps shapes/dtypes and asserts allclose (exact for integer
 paths) against its ref.py oracle, per the kernel-layout convention.
@@ -185,3 +185,23 @@ class TestReservoirStep:
         np.testing.assert_allclose(np.asarray(states_kernel),
                                    np.asarray(states_ref).transpose(1, 0, 2),
                                    rtol=1e-4, atol=1e-4)
+
+
+class TestPlatformRule:
+    """How a kernel runs is derived from the platform, never passed in."""
+
+    def test_cpu_interprets_and_tpu_compiles(self):
+        from repro.kernels.platform import interpret_for
+        assert interpret_for("cpu") is True
+        assert interpret_for("tpu") is False
+
+    def test_platform_without_rule_is_refused(self):
+        from repro.kernels.platform import interpret_for
+        with pytest.raises(RuntimeError, match="'gpu'"):
+            interpret_for("gpu")
+
+    def test_this_process_interprets(self):
+        import jax
+        from repro.kernels.platform import pallas_interpret
+        assert jax.default_backend() == "cpu"
+        assert pallas_interpret() is True

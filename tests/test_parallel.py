@@ -13,9 +13,8 @@ from jax.sharding import AbstractMesh, PartitionSpec as P
 from repro.parallel.sharding import (batch_spec, data_axis_names,
                                      resolve_axes)
 
-# jax 0.4.37's AbstractMesh takes a single tuple of (name, size) pairs.
-MESH = AbstractMesh((("data", 16), ("model", 16)))
-MESH3 = AbstractMesh((("pod", 2), ("data", 16), ("model", 16)))
+MESH = AbstractMesh((16, 16), ("data", "model"))
+MESH3 = AbstractMesh((2, 16, 16), ("pod", "data", "model"))
 
 
 class TestLogicalRules:

@@ -306,5 +306,5 @@ class TestQuotasAndDeadlines:
         p = _params(6)
         a = engine_for(p, "xla")
         assert engine_for(p, "xla") is a
-        b = engine_for(p, "xla", interpret=True)   # kwargs -> no cache
+        b = engine_for(p, "xla", specialize=True)  # kwargs -> no cache
         assert b is not a

@@ -302,3 +302,24 @@ class TestEngineCache:
         eng1 = engine_for(p2)
         assert eng1 is not eng0
         assert eng1._w_out is p2.w_out
+
+
+class TestEngineGuards:
+    @pytest.mark.parametrize("backend", ["xla", "pallas"])
+    def test_int8_state_wider_than_int8_is_refused(self, backend):
+        """Every int8 recurrence multiplies the requantized state as an
+        int8 operand, so a wider state is refused, never clipped."""
+        cfg = ESNConfig(reservoir_dim=64, element_sparsity=0.8,
+                        mode="int8-csd", state_bits=12, block=32)
+        with pytest.raises(ValueError, match="state_bits"):
+            ReservoirEngine(init_esn(cfg), backend=backend)
+
+    def test_batch_tile_padding_lands_in_stats(self):
+        """A 20-row batch runs as three 8-row tiles: 24 rows executed."""
+        eng = ReservoirEngine(_params(dim=64), backend="pallas",
+                              stats=ServeStats())
+        assert eng.program.batch_tiling(20) == (8, 3, 24)
+        eng.rollout(jnp.ones((20, 4, 1), jnp.float32))
+        assert eng.stats.sequences == 24
+        assert eng.stats.steps_real == 80
+        assert eng.stats.padding_efficiency == pytest.approx(20 / 24)
