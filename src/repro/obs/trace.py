@@ -5,8 +5,11 @@ enough history to reconstruct *why* the last N requests were slow (queue
 wait vs. chunk stall vs. an autotune recompile) without growing without
 bound under sustained traffic.  Spans carry:
 
-* ``name``      — the stage (``request.queued``, ``scheduler.chunk``,
+* ``name``      — the stage (``request.queued``, ``scheduler.step``,
   ``engine.dispatch``, ``autotune.trial``, ...);
+* ``parent``    — the name of the wall-clock span open around it when it
+  was recorded (``scheduler.admit`` sits under ``scheduler.step``), so a
+  phase's self time is its duration less its children's;
 * ``trace_id``  — threaded from ``SubmitSpec.trace_id`` through every
   stage a request touches, so one grep over the JSONL dump reassembles a
   request's whole lifecycle;
@@ -24,8 +27,6 @@ import collections
 import dataclasses
 import itertools
 import json
-import time
-from contextlib import contextmanager
 from typing import Any
 
 __all__ = ["Span", "Tracer"]
@@ -45,6 +46,7 @@ class Span:
     trace_id: str | None = None
     clock: str = "wall"
     attrs: dict = dataclasses.field(default_factory=dict)
+    parent: str | None = None
 
     @property
     def duration_s(self) -> float:
@@ -53,7 +55,8 @@ class Span:
     def as_dict(self) -> dict:
         return {"name": self.name, "start": self.start, "end": self.end,
                 "duration_s": self.duration_s, "trace_id": self.trace_id,
-                "clock": self.clock, "attrs": self.attrs}
+                "clock": self.clock, "parent": self.parent,
+                "attrs": self.attrs}
 
 
 class Tracer:
@@ -77,26 +80,17 @@ class Tracer:
 
     def record(self, name: str, start: float, end: float | None = None, *,
                trace_id: str | None = None, clock: str = "wall",
-               **attrs: Any) -> Span:
+               parent: str | None = None, **attrs: Any) -> Span:
         """Record one finished span (``end`` defaults to ``start`` — an
         instant event)."""
         span = Span(name=name, start=float(start),
                     end=float(start if end is None else end),
-                    trace_id=trace_id, clock=clock, attrs=attrs)
+                    trace_id=trace_id, clock=clock, attrs=attrs,
+                    parent=parent)
         if len(self._spans) == self.capacity:
             self.dropped += 1
         self._spans.append(span)
         return span
-
-    @contextmanager
-    def span(self, name: str, *, trace_id: str | None = None, **attrs: Any):
-        """Wall-clock context manager: times the enclosed block."""
-        t0 = time.perf_counter()
-        try:
-            yield
-        finally:
-            self.record(name, t0, time.perf_counter(), trace_id=trace_id,
-                        clock="wall", **attrs)
 
     def spans(self, *, name: str | None = None,
               trace_id: str | None = None) -> list:

@@ -62,6 +62,12 @@ _DONATION_WARNING = "Some donated buffers were not usable"
 _WARNED_DEADLINE = False
 
 
+def _call_span(deferred: bool) -> str:
+    """The span of one engine call: deferred calls time the dispatch only,
+    synced ones the device wait too, so the two never share a name."""
+    return "engine.dispatch" if deferred else "engine.rollout"
+
+
 def donated_call(fn, u, x0b):
     """Invoke a donated rollout with the no-op-donation warning muted
     (shared by the single-device and sharded dispatch paths)."""
@@ -378,14 +384,9 @@ class ReservoirEngine:
                 seconds=seconds, deferred=defer,
                 real_steps=batch * steps if real_steps is None
                 else real_steps)
-            # deferred calls timed dispatch only; synced calls include the
-            # device wait — two different span names so the trace never
-            # conflates the two measurements.
-            obs.span("engine.dispatch" if defer else "engine.rollout",
-                     t0, t0 + seconds, backend=self.backend,
-                     batch=batch, steps=steps, deferred=defer)
-            obs.observe("engine_rollout_seconds", seconds,
-                        backend=self.backend)
+            obs.span(_call_span(defer), t0, t0 + seconds,
+                     backend=self.backend, batch=batch, steps=steps,
+                     deferred=defer)
         return out
 
     def _resolve_want(self, want_states: bool | None) -> bool:
@@ -417,9 +418,11 @@ class ReservoirEngine:
         u = jnp.asarray(inputs)
         x0b = jnp.asarray(x0, jnp.float32)
         b, t = u.shape[0], u.shape[1]
-        t0 = time.perf_counter()
-        out, xf = self._dispatch(u, x0b, not want_states, True, donate_state)
-        self._record(out, b, t, t0, real_steps, defer=defer_sync)
+        with obs.annotation(_call_span(defer_sync)):
+            t0 = time.perf_counter()
+            out, xf = self._dispatch(u, x0b, not want_states, True,
+                                     donate_state)
+            self._record(out, b, t, t0, real_steps, defer=defer_sync)
         return out, xf
 
     def submit(self, spec: SubmitSpec) -> RolloutResult:

@@ -56,7 +56,8 @@ class QueuedRequest:
     The scheduler fills the ``*_time`` fields as the request moves through
     the system (all on the server's clock): ``admit_time`` when it takes a
     slot, ``first_output_time`` when its first chunk of predictions is
-    ready, ``finish_time`` when it retires.
+    ready, ``finish_time`` when it retires.  ``submit_wall`` is the host's
+    wall clock at submit, stamped only while tracing is on.
 
     ``deadline`` (absolute, on the same clock) bounds the queue wait: a
     request still queued past it is dropped at the next admission sweep —
@@ -87,6 +88,7 @@ class QueuedRequest:
     #                                      RolloutResult, not a bare array
     trace_id: str | None = None          # observability correlation id
     #                                      (threads through every span)
+    submit_wall: float | None = None     # perf_counter at submit (traced)
 
     @property
     def uid(self) -> Any:
@@ -179,22 +181,32 @@ class ContinuousBatcher:
         self._chunks: list[list] = [[] for _ in range(n_slots)]
         self._states = self._place(
             jnp.zeros((n_slots, self._dim), jnp.float32))
+        self._zero_row = jnp.zeros((self._dim,), jnp.float32)
+        self._walked = 0                        # chunk entries the syncs'
+        #                                         rewrites walked (traced)
+
+        # the pool's own programs, named for the profiler's trace; each
+        # donated write keeps admission O(request) on accelerators instead
+        # of copying the whole pooled buffer
+        def pool_state_set(states, slot, row):
+            return self._constrain(states.at[slot].set(row))
+
+        def pool_gather(u_dev, idx):
+            return self._constrain(u_dev[jnp.arange(u_dev.shape[0]), idx])
+
+        def pool_lane_write(buf, slot, lanes):
+            return self._constrain(jax.lax.dynamic_update_slice(
+                buf, lanes[None], (slot, 0, 0, 0)))
+
+        self._state_set = jax.jit(pool_state_set, donate_argnums=(0,))
         self._max_chunks = 4                    # input lanes; doubles on
         #                                         demand (longer requests)
         if zero_copy:
             self._u_dev = self._place(jnp.zeros(
                 (n_slots, self._max_chunks, chunk_steps, self._in_dim),
                 jnp.float32))
-            self._gather = jax.jit(
-                lambda u_dev, idx: self._constrain(
-                    u_dev[jnp.arange(u_dev.shape[0]), idx]))
-            # donated in-place lane write: admission cost stays O(request)
-            # on accelerators instead of copying the whole pooled buffer
-            self._lane_set = jax.jit(
-                lambda buf, slot, lanes: self._constrain(
-                    jax.lax.dynamic_update_slice(
-                        buf, lanes[None], (slot, 0, 0, 0))),
-                donate_argnums=(0,))
+            self._gather = jax.jit(pool_gather)
+            self._lane_set = jax.jit(pool_lane_write, donate_argnums=(0,))
         self.last_take: dict = {}               # slot -> steps, last chunk
         self.last_retired_slots: list = []
         self.last_models: dict = {}             # slot -> model, last chunk
@@ -252,12 +264,11 @@ class ContinuousBatcher:
         if not self.want_states and not self.engine.has_readout:
             return      # run_chunk will raise the clear "readout not
             #             trained" error; nothing sane to warm
+        # admission's device ops: one warm call each compiles the program
+        # every slot index reuses (the index is an operand)
+        self._states = self._state_set(self._states, 0, self._zero_row)
         if self.zero_copy:
-            # admission's device ops: one warm call each compiles the
-            # program every slot index reuses (the index is an operand)
             self._gather(self._u_dev, jnp.zeros(self.n_slots, jnp.int32))
-            row = jnp.zeros((self._dim,), jnp.float32)
-            self._states.at[0].set(row)
             self._u_dev = self._lane_set(
                 self._u_dev, 0,
                 jnp.zeros(self._u_dev.shape[1:], jnp.float32))
@@ -351,10 +362,17 @@ class ContinuousBatcher:
                 self._u_dev, slot,
                 jnp.asarray(padded.reshape(self._max_chunks, cs, -1)))
         x0 = qreq.request.x0
-        row = (jnp.zeros((self._dim,), jnp.float32) if x0 is None
+        row = (self._zero_row if x0 is None
                else jnp.asarray(x0, jnp.float32))
-        self._states = self._states.at[slot].set(row)
+        self._states = self._state_set(self._states, slot, row)
         return slot
+
+    def admit_h2d_bytes(self, qreq: QueuedRequest) -> int:
+        """Bytes :meth:`admit` copied host->device for ``qreq``: its input
+        lanes on the zero-copy path, and its ``x0`` row if it has one."""
+        lanes = (self._max_chunks * self.chunk_steps * self._in_dim * 4
+                 if self.zero_copy else 0)
+        return lanes + (0 if qreq.request.x0 is None else self._dim * 4)
 
     def run_chunk(self) -> tuple[list[tuple[QueuedRequest, np.ndarray]], int]:
         """Roll every slot ``chunk_steps`` forward.
@@ -379,31 +397,36 @@ class ContinuousBatcher:
         """
         cs = self.chunk_steps
         take: dict[int, int] = {}
-        if self.zero_copy:
-            # ONE jitted gather assembles the (n_slots, cs, I) chunk from
-            # the device-resident input buffer — no host->device copy and
-            # no per-slot dispatch in the hot loop.  Free slots gather
-            # lane 0 (stale or zero rows): their output is discarded and
-            # their state is re-seeded at admission, so the rows are
-            # inert ballast exactly like the zero rows of the host path.
-            idx = np.zeros(self.n_slots, np.int32)
-            for i, q in enumerate(self._slots):
-                if q is None:
-                    continue
-                idx[i] = self._pos[i] // cs
-                take[i] = min(cs, q.length - self._pos[i])
-            u = self._gather(self._u_dev, jnp.asarray(idx))
-        else:
-            u_host = np.zeros((self.n_slots, cs, self._in_dim), np.float32)
-            for i, q in enumerate(self._slots):
-                if q is None:
-                    continue
-                seg = np.asarray(
-                    q.request.inputs[self._pos[i]:self._pos[i] + cs],
-                    np.float32)
-                u_host[i, :len(seg)] = seg
-                take[i] = len(seg)
-            u = self._place(u_host)
+        with obs.timed_span("scheduler.gather") as span:
+            if self.zero_copy:
+                # ONE jitted gather assembles the (n_slots, cs, I) chunk
+                # from the device-resident input buffer — no host->device
+                # copy and no per-slot dispatch in the hot loop.  Free
+                # slots gather lane 0 (stale or zero rows): their output
+                # is discarded and their state is re-seeded at admission,
+                # so the rows are inert ballast exactly like the zero rows
+                # of the host path.
+                idx = np.zeros(self.n_slots, np.int32)
+                for i, q in enumerate(self._slots):
+                    if q is None:
+                        continue
+                    idx[i] = self._pos[i] // cs
+                    take[i] = min(cs, q.length - self._pos[i])
+                u = self._gather(self._u_dev, jnp.asarray(idx))
+            else:
+                u_host = np.zeros((self.n_slots, cs, self._in_dim),
+                                  np.float32)
+                for i, q in enumerate(self._slots):
+                    if q is None:
+                        continue
+                    seg = np.asarray(
+                        q.request.inputs[self._pos[i]:self._pos[i] + cs],
+                        np.float32)
+                    u_host[i, :len(seg)] = seg
+                    take[i] = len(seg)
+                u = self._place(u_host)
+            if span is not None:
+                span.attrs["live"] = len(take)
         # group occupied slots by pinned (engine, contract); slot order
         # inside and across groups is deterministic (dict insertion
         # follows slot index)
@@ -455,7 +478,10 @@ class ContinuousBatcher:
                     self._chunks[i].append((chunk, take[i]))
             else:
                 self.host_syncs += 1
-                out_h = np.asarray(out)
+                with obs.timed_span("scheduler.sync") as span:
+                    out_h = np.asarray(out)
+                    if span is not None:
+                        span.attrs["d2h_bytes"] = out_h.nbytes
                 for i in slots:
                     self._chunks[i].append(out_h[i, :take[i]].copy())
         self._states = new_states if new_states is not None else prev
@@ -468,14 +494,19 @@ class ContinuousBatcher:
         # retire in a second pass: a retirement materializes the shared
         # chunk buffer (rewriting every rider's entry), so every rider
         # must have its entry before the first retiree triggers that
-        for i in take:
-            q = self._slots[i]
-            if self._pos[i] >= q.length:
-                retired.append((q, self._assemble(i)))
-                retired_slots.append(i)
-                self._slots[i] = None
-                self._chunks[i] = []
-                self._slot_engines[i] = self.engine
+        with obs.timed_span("scheduler.retire") as span:
+            walked = self._walked
+            for i in take:
+                q = self._slots[i]
+                if self._pos[i] >= q.length:
+                    retired.append((q, self._assemble(i)))
+                    retired_slots.append(i)
+                    self._slots[i] = None
+                    self._chunks[i] = []
+                    self._slot_engines[i] = self.engine
+            if span is not None:
+                span.attrs.update(retired=len(retired),
+                                  entries_walked=self._walked - walked)
         # per-slot view of the chunk just run, for per-shard/tenant
         # telemetry
         self.last_take = dict(take)
@@ -524,7 +555,11 @@ class ContinuousBatcher:
         rewritten to its own trimmed row copy, so the full-width buffer
         (device AND host) is immediately collectable — a long-lived rider
         never pins pool-width chunk buffers."""
-        host = np.asarray(chunk.dev)
+        with obs.timed_span("scheduler.sync") as span:
+            host = np.asarray(chunk.dev)
+            if span is not None:
+                span.attrs["d2h_bytes"] = host.nbytes
+                self._walked += sum(map(len, self._chunks))
         chunk.dev = None
         self.host_syncs += 1
         for s, entries in enumerate(self._chunks):
@@ -737,6 +772,8 @@ class AsyncReservoirServer:
                                  else float(deadline),
                                  trace_id=obs.new_trace_id())
         self._seq += 1
+        if obs.tracer() is not None:
+            qreq.submit_wall = time.perf_counter()
         if self.admission is not None:
             verdict = self.admission.admit(self, qreq)
             if verdict is not None:
@@ -831,6 +868,18 @@ class AsyncReservoirServer:
             self._timeout(qreq)
 
     def _admit_arrived(self) -> None:
+        with obs.timed_span("scheduler.admit") as span:
+            seated = self._admit_sweep()
+            if span is not None:
+                span.attrs.update(
+                    admitted=len(seated),
+                    h2d_bytes=sum(map(self.batcher.admit_h2d_bytes, seated)))
+
+    def _admit_sweep(self) -> list:
+        """Seat every arrived request the pool and quotas allow; returns
+        the seated.  A first seating of a request submitted while tracing
+        was on records its wall-clock ``request.wait`` from submit."""
+        seated = []
         held: list[tuple[float, int, QueuedRequest]] = []
         while self._queue and self._queue[0][0] <= self.now:
             qreq = self._queue[0][2]
@@ -858,6 +907,7 @@ class AsyncReservoirServer:
             heapq.heappop(self._queue)
             qreq.admit_time = self.now
             slot = self.batcher.admit(qreq)
+            seated.append(qreq)
             if qreq.requeued:
                 qreq.requeued = False
             else:
@@ -868,11 +918,16 @@ class AsyncReservoirServer:
                 obs.span("request.queued", qreq.arrival_time, self.now,
                          trace_id=qreq.trace_id, clock="server",
                          uid=str(qreq.uid), slot=slot)
+                if qreq.submit_wall is not None:
+                    obs.span("request.wait", qreq.submit_wall,
+                             time.perf_counter(), trace_id=qreq.trace_id,
+                             uid=str(qreq.uid), slot=slot)
                 ts = self._tstats(qreq.model)
                 if ts is not None:
                     ts.record_admission(wait)
         for entry in held:
             heapq.heappush(self._queue, entry)
+        return seated
 
     # -- results -------------------------------------------------------------
     def _obs_labels(self, qreq: QueuedRequest, slot: int | None) -> dict:
@@ -918,9 +973,23 @@ class AsyncReservoirServer:
         deaths into the elastic ``shrink()`` path."""
 
     def step(self) -> bool:
-        """Admit + one chunk + retire.  Returns False once drained."""
+        """Admit + one chunk + retire.  Returns False once drained.
+
+        Traced, the step is one ``scheduler.step`` span (``chunk``: whether
+        a chunk ran) over its phases: ``scheduler.admit``, then the
+        chunk's ``scheduler.gather``, ``engine.dispatch`` and
+        ``scheduler.retire`` (with its ``scheduler.sync`` waits), then
+        ``scheduler.deliver``."""
+        with obs.timed_span("scheduler.step") as span:
+            alive, chunk = self._step()
+            if span is not None:
+                span.attrs["chunk"] = chunk
+        return alive
+
+    def _step(self) -> tuple[bool, bool]:
+        """:meth:`step`'s work; returns (not drained, a chunk ran)."""
         if self.drained:
-            return False
+            return False, False
         if self.batcher.live == 0 and self._queue:
             # pool idle: fast-forward the clock to the next arrival
             self.now = max(self.now, self._queue[0][0])
@@ -931,11 +1000,17 @@ class AsyncReservoirServer:
         if self.batcher.live == 0:
             # everything at the head expired (or only future arrivals are
             # left): no chunk to run this step
-            return not self.drained
+            return not self.drained, False
         t0 = time.perf_counter()
-        chunk_start = self.now
         retired, real_steps = self.batcher.run_chunk()
         wall = time.perf_counter() - t0
+        with obs.timed_span("scheduler.deliver"):
+            self._deliver(retired, real_steps, wall)
+        return True, True
+
+    def _deliver(self, retired: list, real_steps: int, wall: float) -> None:
+        """After a chunk: advance the clock by its charge, sweep deadlines,
+        count it, and answer and mark its requests."""
         dt = wall if self.chunk_time is None else self.chunk_time
         if self.fault_plan is not None:
             # straggler windows inflate the chunk's charge; retry backoff
@@ -952,8 +1027,6 @@ class AsyncReservoirServer:
         self.stats.record_chunk(
             live_steps=real_steps,
             total_steps=self.batcher.n_slots * self.batcher.chunk_steps)
-        obs.span("scheduler.chunk", chunk_start, self.now, clock="server",
-                 live_steps=real_steps, retired=len(retired))
         obs.observe("chunk_seconds", wall)
         # per-slot shard labels for this chunk's retirees (run_chunk
         # already freed their slots, so read its per-chunk view)
@@ -997,7 +1070,6 @@ class AsyncReservoirServer:
                 if isinstance(res, RolloutResult):
                     res.timings["first_output_time"] = self.now
                     res.timings["ttfp_s"] = ttfp
-        return True
 
     def run(self) -> dict:
         """Drain the queue; returns ``{uid: RolloutResult}`` (raw arrays

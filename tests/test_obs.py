@@ -17,6 +17,12 @@ Load-bearing properties:
 """
 
 import json
+import os
+import subprocess
+import sys
+import threading
+import types
+from pathlib import Path
 
 import numpy as np
 import pytest
@@ -27,9 +33,11 @@ from repro import obs
 from repro.core.esn import ESNConfig, fit_readout, init_esn, run_reservoir
 from repro.obs.metrics import (DEFAULT_LATENCY_BUCKETS, HistogramData,
                                MetricsRegistry)
+from repro.serve import scheduler
 from repro.serve import (AsyncReservoirServer, ReservoirEngine, ServeStats,
                          SubmitSpec)
 
+import jax
 import jax.numpy as jnp
 
 
@@ -223,8 +231,12 @@ class TestServeObservability:
             assert "request.enqueue" in names
             assert "request.queued" in names
             assert "request.serve" in names
-            assert all(s.clock == "server"
-                       for s in tr.spans(trace_id=tid))
+            assert "request.wait" in names
+            # each span's clock by name: the lifecycle on the server's
+            # virtual clock, the queue wait on the host's wall clock
+            for s in tr.spans(trace_id=tid):
+                assert s.clock == ("wall" if s.name == "request.wait"
+                                   else "server"), s.name
 
     def test_explicit_trace_id_wins(self):
         obs.configure()
@@ -264,6 +276,185 @@ class TestServeObservability:
             assert "trace_id" not in res.timings
             assert "seconds" in res.timings
         assert obs.metrics() is None and obs.tracer() is None
+
+
+# -- wall-clock phase spans --------------------------------------------------
+PHASES = {"scheduler.admit", "scheduler.gather", "scheduler.retire",
+          "scheduler.deliver"}
+
+
+def _steps_and_children(spans):
+    """Each ``scheduler.step`` span with the spans recorded under it."""
+    steps = sorted((s for s in spans if s.name == "scheduler.step"),
+                   key=lambda s: s.start)
+    kids = {id(st): [] for st in steps}
+    for s in spans:
+        if s.parent == "scheduler.step":
+            owner = [st for st in steps if st.start <= s.start <= st.end]
+            assert len(owner) == 1, s
+            kids[id(owner[0])].append(s)
+    return [(st, kids[id(st)]) for st in steps]
+
+
+def _check_phase_spans(tracer, chunks: int, engine_span: str) -> None:
+    """One ``scheduler.step`` per ``step()``, one that ran a chunk per
+    chunk, each chunk's phases parented to its step and no longer than
+    it in all; every ``scheduler.sync`` under a phase of its step."""
+    spans = tracer.spans()
+    assert tracer.dropped == 0
+    steps = _steps_and_children(spans)
+    assert sum(st.attrs["chunk"] for st, _ in steps) == chunks
+    for st, kids in steps:
+        assert st.parent is None and st.clock == "wall"
+        names = sorted(k.name for k in kids)
+        if st.attrs["chunk"]:
+            assert set(names) - {"scheduler.sync"} == PHASES | {engine_span}
+        assert sum(k.duration_s for k in kids) <= st.duration_s
+        for k in kids:
+            assert st.start <= k.start <= k.end <= st.end
+    for s in tracer.spans(name="scheduler.sync"):
+        assert s.parent in ("scheduler.retire", "scheduler.step")
+        assert s.attrs["d2h_bytes"] > 0
+    for s in tracer.spans(name="scheduler.retire"):
+        assert set(s.attrs) == {"retired", "entries_walked"}
+    admits = tracer.spans(name="scheduler.admit")
+    assert all(set(s.attrs) == {"admitted", "h2d_bytes"} for s in admits)
+    assert {s.parent for s in tracer.spans(name="request.wait")} == {
+        "scheduler.admit"}
+
+
+class TestPhaseSpans:
+    @pytest.mark.parametrize("zero_copy", [False, True])
+    def test_one_step_span_per_chunk_with_its_phases(self, zero_copy):
+        obs.configure()
+        eng = ReservoirEngine(_params(), backend="xla", stats=ServeStats())
+        srv = AsyncReservoirServer(eng, n_slots=4, chunk_steps=8,
+                                   zero_copy=zero_copy)
+        rng = np.random.default_rng(0)
+        for i in range(6):
+            srv.submit(SubmitSpec(
+                rng.standard_normal((10 + 3 * i, 1)).astype(np.float32),
+                uid=i))
+        srv.run()
+        tr = obs.tracer()
+        _check_phase_spans(tr, srv.stats.chunks,
+                           "engine.dispatch" if zero_copy
+                           else "engine.rollout")
+        assert not tr.spans(name="scheduler.chunk")
+        admitted = sum(s.attrs["admitted"]
+                       for s in tr.spans(name="scheduler.admit"))
+        retired = sum(s.attrs["retired"]
+                      for s in tr.spans(name="scheduler.retire"))
+        assert admitted == retired == 6
+        # the zero-copy pool uploads each request's lanes at admission
+        h2d = sum(s.attrs["h2d_bytes"]
+                  for s in tr.spans(name="scheduler.admit"))
+        assert (h2d > 0) == zero_copy
+        assert len(tr.spans(name="scheduler.sync")) == \
+            srv.batcher.host_syncs
+        assert len(tr.spans(name="request.wait")) == 6
+
+    def test_disabled_reads_no_clock_and_builds_no_annotation(
+            self, monkeypatch):
+        import jax.profiler
+
+        def refuse(*_a, **_kw):
+            raise AssertionError("built with tracing off")
+
+        reads = []
+
+        def perf_counter():
+            reads.append(1)
+            return 0.0
+
+        monkeypatch.setattr(jax.profiler, "TraceAnnotation", refuse)
+        monkeypatch.setattr(obs, "time", types.SimpleNamespace(
+            perf_counter=refuse))
+        monkeypatch.setattr(scheduler, "time", types.SimpleNamespace(
+            perf_counter=perf_counter))
+        closed = obs.configure()
+        obs.disable()
+        assert obs.timed_span("a", x=1) is obs.timed_span("b")
+        _eng, srv, results = _serve(n=3)
+        assert len(results) == 3 and srv.stats.chunks > 0
+        # the step's own reads only: the chunk's wall time, start and end
+        assert len(reads) == 2 * srv.stats.chunks
+        assert obs.tracer() is None and obs.detached() is closed
+        assert len(closed.tracer) == 0
+
+    def test_traced_step_mirrors_phases_into_profiler(self, monkeypatch):
+        import jax.profiler
+        entered = []
+
+        class Recording:
+            def __init__(self, name):
+                self.name = name
+
+            def __enter__(self):
+                entered.append(self.name)
+
+            def __exit__(self, *exc):
+                return None
+
+        monkeypatch.setattr(jax.profiler, "TraceAnnotation", Recording)
+        obs.configure()
+        _serve(n=2, zero_copy=True)
+        names = set(entered)
+        assert PHASES | {"scheduler.step", "scheduler.sync",
+                         "engine.dispatch"} <= names
+        assert entered.count("scheduler.step") == len(
+            obs.tracer().spans(name="scheduler.step"))
+
+
+class TestSpanParents:
+    def test_nested_timed_spans_and_recorded_spans(self):
+        obs.configure()
+        with obs.timed_span("outer") as outer:
+            with obs.timed_span("inner", k=1) as inner:
+                inner.attrs["late"] = 2
+                obs.span("point", 0.0)
+            obs.span("beside", 0.0)
+            assert outer is not None
+        obs.span("alone", 0.0)
+        got = {s.name: s for s in obs.tracer().spans()}
+        assert got["outer"].parent is None
+        assert got["inner"].parent == "outer"
+        assert got["inner"].attrs == {"k": 1, "late": 2}
+        assert got["point"].parent == "inner"
+        assert got["beside"].parent == "outer"
+        assert got["alone"].parent is None
+        assert got["outer"].start <= got["inner"].start
+        assert got["inner"].end <= got["outer"].end
+
+    def test_each_thread_keeps_its_own_parents(self):
+        obs.configure()
+        with obs.timed_span("main"):
+            t = threading.Thread(target=lambda: obs.span("other", 0.0))
+            t.start()
+            t.join(timeout=10)
+            assert not t.is_alive()
+        assert obs.tracer().spans(name="other")[0].parent is None
+
+    def test_exception_closes_the_span(self):
+        obs.configure()
+        with pytest.raises(ValueError):
+            with obs.timed_span("failing"):
+                raise ValueError("x")
+        obs.span("after", 0.0)
+        assert obs.tracer().spans(name="failing")
+        assert obs.tracer().spans(name="after")[0].parent is None
+
+    def test_detached_keeps_the_closed_window(self):
+        state = obs.configure()
+        with obs.timed_span("kept"):
+            pass
+        obs.disable()
+        assert obs.detached() is state
+        assert obs.timed_span("off") is obs.timed_span("off")
+        obs.disable()
+        assert obs.detached() is state
+        obs.configure()
+        assert obs.detached() is None
 
 
 # -- stats render ------------------------------------------------------------
@@ -322,3 +513,48 @@ class TestDistObservability:
         for p in (50, 99, 99.9):
             assert qw.percentile(p) > 0.0
         assert m.histogram("ttfp_seconds").count() == 5
+
+
+class TestPhaseSpansMultiDevice:
+    """The distributed server inherits the phase spans: one sharded pool
+    over four devices (run in a child with four virtual CPU devices)."""
+
+    @pytest.mark.skipif(len(jax.devices()) < 4,
+                        reason="needs 4 devices (run by the subprocess "
+                               "test below)")
+    @pytest.mark.parametrize("zero_copy", [False, True])
+    def test_sharded_server_records_the_same_phases(self, zero_copy):
+        from repro.dist import (DistributedReservoirServer,
+                                ShardedReservoirEngine)
+        from repro.launch.mesh import make_data_mesh
+        obs.configure()
+        eng = ShardedReservoirEngine(_params(), mesh=make_data_mesh(4),
+                                     backend="xla", stats=ServeStats())
+        srv = DistributedReservoirServer(eng, slots_per_shard=2,
+                                         chunk_steps=8, zero_copy=zero_copy)
+        rng = np.random.default_rng(4)
+        for i in range(12):
+            srv.submit(SubmitSpec(
+                rng.standard_normal((10 + 2 * i, 1)).astype(np.float32),
+                uid=i))
+        srv.run()
+        _check_phase_spans(obs.tracer(), srv.stats.chunks,
+                           "engine.dispatch" if zero_copy
+                           else "engine.rollout")
+        admitted = sum(s.attrs["admitted"]
+                       for s in obs.tracer().spans(name="scheduler.admit"))
+        assert admitted == 12
+
+    def test_subprocess_four_devices(self):
+        env = dict(os.environ)
+        env["XLA_FLAGS"] = ("--xla_force_host_platform_device_count=4 "
+                            + env.get("XLA_FLAGS", "")).strip()
+        env["PYTHONPATH"] = "src" + os.pathsep + env.get("PYTHONPATH", "")
+        out = subprocess.run(
+            [sys.executable, "-m", "pytest", "-x", "-q", "-p",
+             "no:cacheprovider", "tests/test_obs.py", "-k",
+             "MultiDevice and not subprocess"],
+            capture_output=True, text=True, timeout=600, env=env,
+            cwd=str(Path(__file__).parent.parent))
+        assert out.returncode == 0, out.stdout[-3000:] + out.stderr[-2000:]
+        assert "2 passed" in out.stdout
