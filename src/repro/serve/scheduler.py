@@ -103,15 +103,22 @@ class _DeviceChunk:
     """One chunk's full (n_slots, cs, O) device output, shared by every
     sequence that rode in it and host-converted at most once — the
     device->host sync happens when the first rider retires, never in the
-    chunk loop.  At conversion every rider's reference is compacted to
-    its own trimmed row copy, so neither the device buffer nor the
-    full-width host array outlives the sync (a long-lived rider would
-    otherwise pin pool-width buffers for its whole life)."""
+    chunk loop.
 
-    __slots__ = ("dev",)
+    ``riders`` indexes the entries that point at the chunk, as plain
+    ``(slot, entry index)`` ints (a reference to a slot's list would make
+    a chunk -> list -> entry -> chunk cycle that pins the device buffer
+    until a GC pass), so the sync rewrites only those entries.  At
+    conversion every rider's entry becomes its own trimmed row copy, so
+    neither the device buffer nor the full-width host array outlives the
+    sync (a long-lived rider would otherwise pin pool-width buffers for
+    its whole life)."""
+
+    __slots__ = ("dev", "riders")
 
     def __init__(self, dev):
         self.dev = dev
+        self.riders: list[tuple[int, int]] | None = []
 
 
 class ContinuousBatcher:
@@ -182,8 +189,8 @@ class ContinuousBatcher:
         self._states = self._place(
             jnp.zeros((n_slots, self._dim), jnp.float32))
         self._zero_row = jnp.zeros((self._dim,), jnp.float32)
-        self._walked = 0                        # chunk entries the syncs'
-        #                                         rewrites walked (traced)
+        self._walked = 0                        # rider entries the syncs
+        #                                         rewrote (read when traced)
 
         # the pool's own programs, named for the profiler's trace; each
         # donated write keeps admission O(request) on accelerators instead
@@ -475,7 +482,9 @@ class ContinuousBatcher:
                 # device op, no host transfer until a rider retires
                 chunk = _DeviceChunk(out)
                 for i in slots:
-                    self._chunks[i].append((chunk, take[i]))
+                    entries = self._chunks[i]
+                    chunk.riders.append((i, len(entries)))
+                    entries.append((chunk, take[i]))
             else:
                 self.host_syncs += 1
                 with obs.timed_span("scheduler.sync") as span:
@@ -551,21 +560,26 @@ class ContinuousBatcher:
     def _materialize(self, chunk: _DeviceChunk) -> None:
         """THE deferred device->host sync point, paid once per chunk
         buffer no matter how many riders retire from it, and only ever
-        reached from retirement/snapshot paths.  Every rider's entry is
-        rewritten to its own trimmed row copy, so the full-width buffer
-        (device AND host) is immediately collectable — a long-lived rider
-        never pins pool-width chunk buffers."""
+        reached from retirement/snapshot paths.  Only the chunk's riders
+        are visited (at most ``n_slots``, never every slot's list), and
+        each rider's entry is rewritten to its own trimmed row copy, so
+        the full-width buffer (device AND host) is immediately
+        collectable — a long-lived rider never pins pool-width chunk
+        buffers.  An entry no longer pointing at the chunk (its slot was
+        vacated and reseated) is left alone."""
         with obs.timed_span("scheduler.sync") as span:
             host = np.asarray(chunk.dev)
             if span is not None:
                 span.attrs["d2h_bytes"] = host.nbytes
-                self._walked += sum(map(len, self._chunks))
         chunk.dev = None
         self.host_syncs += 1
-        for s, entries in enumerate(self._chunks):
-            for j, (c, n) in enumerate(entries):
-                if c is chunk:
-                    entries[j] = (host[s, :n].copy(), n)
+        riders, chunk.riders = chunk.riders, None
+        self._walked += len(riders)
+        for s, j in riders:
+            entries = self._chunks[s]
+            if j < len(entries) and entries[j][0] is chunk:
+                n = entries[j][1]
+                entries[j] = (host[s, :n].copy(), n)
 
     def _slot_rows(self, slot: int) -> list:
         """A slot's chunk outputs as trimmed host rows (zero-copy path),

@@ -522,6 +522,122 @@ class TestZeroCopyServing:
         assert qr.uid == "z"
         assert np.allclose(out, np.asarray(ref))
 
+    @pytest.mark.parametrize("n_slots,chunk_steps", [(8, 4), (11, 3)])
+    def test_sync_rewrites_only_the_chunks_riders(self, n_slots,
+                                                  chunk_steps):
+        """A sync visits the synced chunk's riders only (at most
+        ``n_slots`` entries), leaves no entry pointing at a synced chunk,
+        and serves the host path's outputs bit for bit."""
+        from repro import obs
+        from repro.serve.scheduler import _DeviceChunk
+        p = _params()
+        lengths = [5 + (7 * i) % 29 for i in range(3 * n_slots)]
+        outs = {}
+        for zero_copy in (False, True):
+            obs.configure()
+            try:
+                _, srv = _server(p, n_slots=n_slots,
+                                 chunk_steps=chunk_steps,
+                                 zero_copy=zero_copy)
+                for r in _requests(lengths, seed=12):
+                    srv.submit(r)
+                while srv.step():
+                    for entries in srv.batcher._chunks:
+                        for e in entries:
+                            assert (not isinstance(e[0], _DeviceChunk)
+                                    or e[0].dev is not None)
+                outs[zero_copy] = srv.results
+                tr = obs.tracer()
+            finally:
+                obs.disable()
+            assert tr.dropped == 0
+        syncs = tr.spans(name="scheduler.sync")
+        retires = tr.spans(name="scheduler.retire")
+        assert sum(s.attrs["retired"] for s in retires) == len(lengths)
+        for r in retires:
+            n_sync = sum(s.parent == "scheduler.retire"
+                         and r.start <= s.start <= r.end for s in syncs)
+            assert r.attrs["entries_walked"] <= n_slots * n_sync
+        assert srv.batcher._walked > 0
+        assert set(outs[True]) == set(outs[False]) == set(range(len(lengths)))
+        for uid in outs[True]:
+            np.testing.assert_array_equal(np.asarray(outs[True][uid].output),
+                                          np.asarray(outs[False][uid].output))
+
+    def test_multi_tenant_chunk_riders_are_their_groups_slots(
+            self, monkeypatch):
+        """Two engines in one zero-copy pool: each chunk launches one
+        device buffer per engine, whose riders are exactly that engine's
+        slots, and every request's output equals a single-tenant pool's."""
+        from repro.serve import ModelRegistry
+        from repro.serve import scheduler as sched
+        pA, pB = _params(seed=1), _params(seed=2, leak=0.55)
+        n_slots, cs = 8, 4
+        lengths = [6 + (5 * i) % 19 for i in range(14)]
+        reqs = _requests(lengths, seed=13)
+        models = ["A" if i % 3 else "B" for i in range(len(reqs))]
+        reg = ModelRegistry(backend="xla")
+        reg.register("A", pA)
+        reg.register("B", pB)
+
+        def serve(model_of):
+            eng = reg.engine("A")
+            eng.stats = ServeStats()
+            srv = AsyncReservoirServer(eng, n_slots=n_slots, chunk_steps=cs,
+                                       chunk_time=1.0, zero_copy=True,
+                                       registry=reg)
+            for r, m in zip(reqs, models):
+                if model_of is None or m == model_of:
+                    srv.submit(SubmitSpec(r.inputs, uid=r.uid, model=m))
+            return srv
+
+        made, riders = [], {}
+
+        class Recording(sched._DeviceChunk):
+            __slots__ = ()
+
+            def __init__(self, dev):
+                super().__init__(dev)
+                made.append(self)
+
+        srv = serve(None)
+        cb = srv.batcher
+        materialize = cb._materialize
+
+        def spy(chunk):
+            riders[id(chunk)] = sorted(s for s, _j in chunk.riders)
+            materialize(chunk)
+
+        cb._materialize = spy
+        monkeypatch.setattr(sched, "_DeviceChunk", Recording)
+        mixed = 0
+        while True:
+            before = len(made)
+            if not srv.step():
+                break
+            new = made[before:]
+            if not new:
+                continue
+            groups = {}
+            for slot, m in cb.last_models.items():
+                groups.setdefault(m, []).append(slot)
+            assert len(new) == len(groups)
+            mixed += len(groups) == 2
+            got = [riders.get(id(c)) or sorted(s for s, _j in c.riders)
+                   for c in new]
+            assert sorted(got) == sorted(sorted(g) for g in groups.values())
+        monkeypatch.undo()
+        assert mixed > 0
+        assert all(c.dev is None and c.riders is None for c in made)
+        res = srv.results
+        assert set(res) == set(range(len(reqs)))
+        for m in ("A", "B"):
+            alone = serve(m).run()
+            for uid, out in alone.items():
+                assert res[uid].timings["model"] == m
+                np.testing.assert_array_equal(np.asarray(res[uid].output),
+                                              np.asarray(out.output))
+
 
 class TestServeStatsZeroDivision:
     def test_all_timed_out_summary_and_render(self):
