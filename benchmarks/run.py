@@ -954,7 +954,7 @@ def serve_autotune():
                 specialize_summary(plan, s.mode, vmem_budget=s.vmem_budget,
                                    crossover=s.crossover,
                                    batch_tile_max=s.batch_tile_max),
-                plan.block, batch, t_steps)
+                plan.block, batch, t_steps, s.backend)
             samples.append((s.backend, feats, meas))
         rel_err = (abs(tuned.predicted_s - tuned.measured_s)
                    / tuned.measured_s)

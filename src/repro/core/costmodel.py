@@ -197,9 +197,9 @@ def design_point(
 
 ROLLOUT_FEATURES = (
     "matmul_macs",     # folded-tile MAC count across the whole rollout
-    "shiftadd_ops",    # unrolled digit adds across the whole rollout
+    "shiftadd_ops",    # digit adds below the crossover across the rollout
     "stream_bytes",    # weight bytes moved (once if resident, per step if
-                       # pipelined — the regime axis of the search)
+                       # pipelined or on XLA — the regime axis of the search)
     "band_steps",      # band-grid iterations (per-band launch overhead)
     "tile_steps",      # batch-tile-grid iterations (per-tile overhead)
     "steps",           # scan/grid steps (per-step dispatch overhead)
@@ -207,10 +207,14 @@ ROLLOUT_FEATURES = (
 
 
 def rollout_cost_features(summary: dict, block: int, batch: int,
-                          steps: int = 1) -> dict:
+                          steps: int = 1, backend: str | None = None) -> dict:
     """Work terms of one specialized schedule over a ``(batch, steps)``
     rollout, computed from :func:`~repro.plan.specialize.specialize_summary`
     counts only — no tile data is ever materialized to price a candidate.
+
+    ``backend="xla"`` prices the folded tiles as XLA reads them: its scan
+    keeps no weight on chip from one step to the next, so they stream
+    every step whatever the regime.
     """
     batch_tile_max = summary.get("batch_tile_max", 16)
     n_tiles = max(1, -(-batch // batch_tile_max))
@@ -219,7 +223,7 @@ def rollout_cost_features(summary: dict, block: int, batch: int,
     itemsize = 4 if summary["mode"] == "fp32" else 1
     tile_bytes = block * block * itemsize
     payload = summary["n_matmul_terms"] * tile_bytes
-    if summary["regime"] == "resident":
+    if summary["regime"] == "resident" and backend != "xla":
         stream = payload                       # hoisted on-chip once
     else:
         stream = payload * steps               # re-streamed every step
@@ -278,17 +282,32 @@ def default_rollout_cost_model(platform: str = "cpu") -> RolloutCostModel:
     kernels run in interpret mode, so its per-term coefficients carry an
     interpreter penalty large enough that pallas never survives pruning
     off-TPU — preserving the XLA-first dispatch the serve tests pin.
+    XLA computes the digits below the crossover from the scattered table,
+    Pallas unrolls them: each backend's ``shiftadd_ops`` term prices its
+    own way.
+
+    measured (TPU v5e, one chip), XLA's terms: the int8 dense fold of a
+    5000-node matrix took 0.64 / 0.67 / 0.80 ms per 16-step launch of 8 /
+    64 / 256 rows, so it streams the folded weights every step (1.48e-12
+    s/B) and multiplies at 1.6e-15 s/MAC; the scattered table of the same
+    matrix (43,446 digits) took 1.77 ms at 8 rows, 3.2e-10 s per
+    digit-row-step at the tuning batch (the gather is nearly flat in the
+    rows, so wider batches cost less per row than this prices).  Pallas's
+    MAC term: the dim-1024 matrix's 64 resident tiles took 0.32 ms per
+    256-row, 16-step launch (7.4e-14 s/MAC); its shift-add and overhead
+    terms are priors (the unroll that would measure them is dropped by the
+    autotuner's VMEM filter wherever it is large).
     """
     if platform == "tpu":
         coeffs = {
-            #       macs    shiftadd stream   band     tile     step  icept
-            "xla": [1e-14, 2e-12, 1.3e-12, 1e-7, 2e-8, 5e-7, 2e-5],
+            #        macs    shiftadd stream   band  tile  step  icept
+            "xla": [1.6e-15, 3.2e-10, 1.48e-12, 1e-7, 2e-8, 5e-7, 2e-5],
             # fused grid: no per-step dispatch back to the host
-            "pallas": [1e-14, 2e-12, 1.3e-12, 5e-8, 1e-8, 2e-8, 1e-5],
+            "pallas": [7.4e-14, 2e-12, 1.3e-12, 5e-8, 1e-8, 2e-8, 1e-5],
         }
     else:
         coeffs = {
-            "xla": [2e-11, 2e-9, 2e-11, 2e-6, 1e-6, 2e-6, 1e-4],
+            "xla": [2e-11, 1e-9, 2e-11, 2e-6, 1e-6, 2e-6, 1e-4],
             # interpret-mode pallas: every grid step is python dispatch
             "pallas": [2e-9, 2e-7, 2e-9, 1e-3, 1e-3, 1e-2, 1e-2],
         }

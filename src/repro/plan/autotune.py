@@ -65,6 +65,12 @@ __all__ = [
 
 BACKENDS = ("xla", "pallas")
 
+# VMEM the Pallas kernel's shift-add unroll holds per output lane and batch
+# row: Mosaic keeps each lane's summed (b_tile, 1) column live, 140-150 B
+# per lane-row on a v5e (compiled for the chip: degree-3 programs of 1280,
+# 1920 and 5000 nodes at 8- and 16-row tiles)
+UNROLL_LANE_ROW_BYTES = 144
+
 # Default tuning shape: small enough to measure in milliseconds, big
 # enough that the regime/backend choice it makes transfers to serve-sized
 # batches (the cache key buckets the batch axis, so other shapes re-tune).
@@ -230,7 +236,13 @@ def candidate_schedules(plan: ExecutionPlan, mode: str,
     default and the axis collapses); batch tiles sweep grid parallelism.
     Candidates whose band packing is infeasible (a single column's folded
     tiles overflow half the budget — ``specialize_rollout`` would raise)
-    are dropped here, so everything returned can actually build.
+    are dropped here, so everything returned can actually build; so are
+    Pallas candidates whose folded tiles and shift-add unroll hold more
+    VMEM than the default budget (the rest of the chip's VMEM is left to
+    the state and the pipeline's blocks).  On the XLA backend the
+    crossover axis moves blocks between folded tiles and the scattered
+    table (:class:`~repro.plan.specialize.ScatteredTable`); on Pallas,
+    between folded tiles and unrolled shift-adds.
     """
     block = plan.block
     budgets = [None, DEFAULT_VMEM_BUDGET, DEFAULT_VMEM_BUDGET // 2,
@@ -247,16 +259,26 @@ def candidate_schedules(plan: ExecutionPlan, mode: str,
             for crossover in crossovers:
                 for tile in tiles:
                     try:
-                        specialize_summary(plan, mode, vmem_budget=budget,
-                                           crossover=crossover,
-                                           batch_tile_max=tile)
+                        summary = specialize_summary(
+                            plan, mode, vmem_budget=budget,
+                            crossover=crossover, batch_tile_max=tile)
                     except ValueError:
                         continue  # infeasible double-buffer packing
+                    if (backend == "pallas" and _pallas_vmem(summary, tile)
+                            > DEFAULT_VMEM_BUDGET):
+                        continue  # more than a kernel's VMEM holds
                     s = Schedule(mode, backend, budget, crossover, tile)
                     if s.key() not in seen:
                         seen.add(s.key())
                         out.append(s)
     return out
+
+
+def _pallas_vmem(summary: dict, batch_tile_max: int) -> int:
+    """VMEM a Pallas program holds whatever the batch: its folded tiles
+    (twice over where they stream) and its shift-add unroll."""
+    return (summary["resident_bytes"] + summary["shiftadd_lanes"]
+            * batch_tile_max * UNROLL_LANE_ROW_BYTES)
 
 
 def predict_cost(plan: ExecutionPlan, schedule: Schedule, batch: int,
@@ -271,7 +293,7 @@ def predict_cost(plan: ExecutionPlan, schedule: Schedule, batch: int,
         crossover=schedule.crossover,
         batch_tile_max=schedule.batch_tile_max)
     feats = costmodel.rollout_cost_features(summary, plan.block, batch,
-                                            steps)
+                                            steps, schedule.backend)
     return model.predict(schedule.backend, feats)
 
 

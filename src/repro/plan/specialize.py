@@ -25,6 +25,13 @@ re-fetched.  This module is the software synthesis step that buys the
   entirely: its few set digits are emitted as static shift-add terms
   (``acc[:, j] += ±(x[:, i] << w)``), the software mirror of the paper's
   synthesized adder trees.
+* **scattered table** — the same digits, summed per matrix entry, as
+  static ELL tables (:class:`ScatteredTable`): per output column the
+  source rows and values, padded to the largest in-degree.  A consumer
+  that can gather (the XLA backend) reads the remainder from the table in
+  one gather-multiply-accumulate, so its program does not grow with the
+  matrix's nonzeros or digits; the Pallas kernel, which cannot gather,
+  unrolls the shift-add terms.
 * **batch tiling** — the batch axis splits into tiles of at most
   ``batch_tile_max`` rows, so a batch-64 rollout runs as grid-parallel
   batch tiles instead of one monolithic VMEM block.  A tile is a multiple
@@ -51,9 +58,12 @@ __all__ = [
     "SA",
     "DEFAULT_BATCH_TILE",
     "RolloutProgram",
+    "ScatteredTable",
+    "scattered_table",
     "specialize_rollout",
     "specialize_summary",
     "int8_recur_reference",
+    "table_product",
 ]
 
 # Term tags in a band schedule (static tuples unrolled at trace time):
@@ -86,6 +96,37 @@ def default_crossover(block: int) -> int:
 
 
 @dataclasses.dataclass(frozen=True)
+class ScatteredTable:
+    """The digits a program leaves off its folded tiles, as ELL tables.
+
+    Column ``j`` of the remainder ``sum(±2^w d_w)`` over the planes below
+    the crossover holds its nonzero entries in ``idx[:, j]`` (source rows)
+    and ``val[:, j]`` (values), padded with row 0 and value 0 to
+    ``degree``, the largest count in any column.  Where a block has no
+    plane at or above the crossover, its values are exactly its quantized
+    entries.  Static numpy: the structure is a compile-time constant of
+    whatever consumes it.
+    """
+
+    idx: np.ndarray            # (degree, cols_pad) int32 source rows
+    val: np.ndarray            # (degree, cols_pad) int32 values
+    entries: int               # nonzero entries (the rest is padding)
+
+    @property
+    def degree(self) -> int:
+        return int(self.idx.shape[0])
+
+    @property
+    def slots(self) -> int:
+        """Entries a product reads, padding included: degree x columns."""
+        return int(self.idx.size)
+
+    @property
+    def nbytes(self) -> int:
+        return int(self.idx.nbytes + self.val.nbytes)
+
+
+@dataclasses.dataclass(frozen=True)
 class RolloutProgram:
     """A matrix-specialized rollout: banded folded tiles + static schedule.
 
@@ -107,11 +148,27 @@ class RolloutProgram:
     n_matmul_terms: int        # folded-tile matmul terms kept
     n_shiftadd_terms: int      # (block, plane-group) shift-add terms
     shiftadd_digits: int       # unrolled digit adds across all SA terms
+    shiftadd_lanes: int        # output lanes the SA digits land in
     resident_bytes: int        # weight bytes on-chip while executing
+    table: ScatteredTable | None = None   # the SA digits, summed per entry
 
     @property
     def n_bands(self) -> int:
         return len(self.schedules)
+
+    @property
+    def kind(self) -> str:
+        """``tiles`` (folded tiles only), ``scattered`` (no tile: every
+        block lies below the crossover) or ``mixed``."""
+        return _kind(self.n_matmul_terms, self.shiftadd_digits)
+
+    @property
+    def table_slots(self) -> int:
+        return 0 if self.table is None else self.table.slots
+
+    @property
+    def table_bytes(self) -> int:
+        return 0 if self.table is None else self.table.nbytes
 
     def batch_tiling(self, batch: int) -> tuple[int, int, int]:
         """(b_tile, n_tiles, b_padded) for a batch of ``batch`` rows.
@@ -131,12 +188,21 @@ class RolloutProgram:
 
     def describe(self) -> str:
         dbl = " x2 (double-buffered)" if self.regime == "pipelined" else ""
+        table = ("" if self.table is None else
+                 f"; table {self.table.entries} entries, degree "
+                 f"{self.table.degree}, {self.table.nbytes} B")
         return (f"{self.mode} {self.regime}: {self.n_bands} band(s), "
                 f"{self.resident_bytes} B weights on-chip{dbl}, "
                 f"{self.n_matmul_terms} matmul terms + "
                 f"{self.n_shiftadd_terms} shift-add terms "
                 f"({self.shiftadd_digits} digit adds, "
-                f"crossover {self.crossover})")
+                f"crossover {self.crossover}){table}")
+
+
+def _kind(n_matmul_terms: int, shiftadd_digits: int) -> str:
+    if not shiftadd_digits:
+        return "tiles"
+    return "mixed" if n_matmul_terms else "scattered"
 
 
 def _int8_block_lowering(plan: ExecutionPlan, di: int, crossover: int):
@@ -191,6 +257,60 @@ def _column_lowerings(plan: ExecutionPlan, mode: str, crossover: int):
                 entries.append((ri, mm, sa))
         out.append(entries)
     return out
+
+
+def _table(plan: ExecutionPlan, cols: list) -> ScatteredTable | None:
+    """The shift-add digits of ``cols``, summed per matrix entry, as ELL
+    tables (None where no digit lies below the crossover)."""
+    bk = plan.block
+    digits = [(ri * bk + i, ci * bk + j, s << w)
+              for ci, entries in enumerate(cols)
+              for ri, _mm, sa in entries for i, j, s, w in sa]
+    if not digits:
+        return None
+    rows, col_ids, vals = (np.asarray(a, np.int64) for a in zip(*digits))
+    # one entry per (column, row): a matrix entry's digits in several
+    # planes below the crossover add up to its remainder value
+    keys, inverse = np.unique(col_ids * plan.rows_pad + rows,
+                              return_inverse=True)
+    sums = np.zeros(len(keys), np.int64)
+    np.add.at(sums, inverse, vals)
+    keep = sums != 0
+    keys, sums = keys[keep], sums[keep]
+    col_of, row_of = keys // plan.rows_pad, keys % plan.rows_pad
+    # keys are sorted by column, then row: each entry's slot is its rank
+    # within its column
+    starts = np.searchsorted(col_of, col_of, side="left")
+    slot = np.arange(len(keys)) - starts
+    degree = int(slot.max()) + 1
+    idx = np.zeros((degree, plan.cols_pad), np.int32)
+    val = np.zeros((degree, plan.cols_pad), np.int32)
+    idx[slot, col_of] = row_of
+    val[slot, col_of] = sums
+    return ScatteredTable(idx=idx, val=val, entries=int(len(keys)))
+
+
+def scattered_table(plan: ExecutionPlan,
+                    crossover: int | None = None) -> ScatteredTable | None:
+    """The int8 program's shift-add remainder at ``crossover`` as ELL
+    tables, cached on the plan per crossover; builds no tile data."""
+    crossover = default_crossover(plan.block) if crossover is None else crossover
+    cache = getattr(plan, "_tables", None)
+    if cache is None:
+        cache = plan._tables = {}
+    if crossover not in cache:
+        cache[crossover] = _table(plan, _lowerings(plan, "int8", crossover))
+    return cache[crossover]
+
+
+def table_product(table: ScatteredTable, xq: jnp.ndarray) -> jnp.ndarray:
+    """Exact ``xq @ remainder`` from the tables: (..., rows) integers ->
+    (..., cols_pad) int32, in one gather-multiply-accumulate whose size is
+    fixed by the table's shape, never by its entries."""
+    g = jnp.take(xq.astype(jnp.int32), jnp.asarray(table.idx.reshape(-1)),
+                 axis=-1)
+    g = g.reshape(xq.shape[:-1] + table.idx.shape)
+    return jnp.sum(g * jnp.asarray(table.val), axis=-2)
 
 
 def _partition(plan: ExecutionPlan, col_mm_counts: np.ndarray,
@@ -251,6 +371,9 @@ def _analyze(plan: ExecutionPlan, mode: str, crossover: int,
                        for entries in cols])
     regime, spans = _partition(plan, counts, tile_bytes, vmem_budget)
     max_terms = max(1, max(int(counts[lo:hi].sum()) for lo, hi in spans))
+    table = scattered_table(plan, crossover) if mode == "int8" else None
+    shiftadd_digits = sum(len(sa) for entries in cols
+                          for _ri, _mm, sa in entries)
     return {
         "cols": cols,
         "spans": spans,
@@ -262,8 +385,15 @@ def _analyze(plan: ExecutionPlan, mode: str, crossover: int,
         "n_matmul_terms": int(counts.sum()),
         "n_shiftadd_terms": sum(1 for entries in cols
                                 for _ri, _mm, sa in entries if sa),
-        "shiftadd_digits": sum(len(sa) for entries in cols
-                               for _ri, _mm, sa in entries),
+        "shiftadd_digits": shiftadd_digits,
+        # output lanes the unrolled digits land in, one (b_tile, 1) column
+        # each in the Pallas kernel
+        "shiftadd_lanes": sum(len({j for _i, j, _s, _w in sa})
+                              for entries in cols for _ri, _mm, sa in entries),
+        "kind": _kind(int(counts.sum()), shiftadd_digits),
+        "table": table,
+        "table_slots": 0 if table is None else table.slots,
+        "table_bytes": 0 if table is None else table.nbytes,
         "resident_bytes": max_terms * tile_bytes * (
             1 if regime == "resident" else 2),
         "crossover": crossover,
@@ -272,7 +402,8 @@ def _analyze(plan: ExecutionPlan, mode: str, crossover: int,
 
 
 _SUMMARY_KEYS = ("mode", "regime", "n_bands", "n_matmul_terms",
-                 "n_shiftadd_terms", "shiftadd_digits", "resident_bytes",
+                 "n_shiftadd_terms", "shiftadd_digits", "shiftadd_lanes",
+                 "kind", "table_slots", "table_bytes", "resident_bytes",
                  "crossover", "vmem_budget", "batch_tile_max")
 
 
@@ -370,10 +501,15 @@ def specialize_rollout(plan: ExecutionPlan, mode: str = "fp32",
         n_matmul_terms=a["n_matmul_terms"],
         n_shiftadd_terms=a["n_shiftadd_terms"],
         shiftadd_digits=a["shiftadd_digits"],
-        resident_bytes=a["resident_bytes"])
+        shiftadd_lanes=a["shiftadd_lanes"],
+        resident_bytes=a["resident_bytes"], table=a["table"])
     cache[key] = program
     obs.span("plan.specialize", t_spec, time.perf_counter(), clock="wall",
-             mode=mode, regime=a["regime"], n_bands=a["n_bands"])
+             mode=mode, regime=a["regime"], n_bands=a["n_bands"],
+             kind=a["kind"], table_bytes=a["table_bytes"],
+             n_matmul_terms=a["n_matmul_terms"],
+             n_shiftadd_terms=a["n_shiftadd_terms"],
+             shiftadd_digits=a["shiftadd_digits"])
     obs.event("specialize", mode=mode, regime=a["regime"])
     return program
 
@@ -384,29 +520,29 @@ def int8_recur_reference(program: RolloutProgram, xq: jnp.ndarray,
 
     ``xq``: (..., rows) quantized states within int8 range -> (...,
     out_cols) int32 — bit-identical to ``FixedMatrix.matvec_int_exact``
-    because every term accumulates in exact int32.  The same schedule the Pallas kernel
-    unrolls, expressed in plain jnp for the XLA backend (and for parity
-    tests).
+    because every term accumulates in exact int32.  The program's folded
+    tiles, as the Pallas kernel multiplies them, plus its shift-add
+    remainder read from the scattered table (:func:`table_product`)
+    rather than unrolled digit by digit.
     """
     assert program.mode == "int8"
     bk = program.block
     xp = jnp.zeros(xq.shape[:-1] + (rows_pad,), jnp.int32
                    ).at[..., : xq.shape[-1]].set(xq.astype(jnp.int32))
-    pieces = []
-    for bi, band in enumerate(program.schedules):
-        for ci, terms in band:
-            acc = jnp.zeros(xq.shape[:-1] + (bk,), jnp.int32)
-            for term in terms:
-                if term[0] == MM:
-                    _tag, slot, shift, ri = term
+    out = (None if program.table is None
+           else table_product(program.table, xp))
+    if program.n_matmul_terms or out is None:
+        pieces = []
+        for bi, band in enumerate(program.schedules):
+            for ci, terms in band:
+                acc = jnp.zeros(xq.shape[:-1] + (bk,), jnp.int32)
+                for _tag, slot, shift, ri in (t for t in terms
+                                              if t[0] == MM):
                     xs = xp[..., ri * bk:(ri + 1) * bk].astype(jnp.int8)
                     acc = acc + (jnp.matmul(
                         xs, program.data[bi, slot],
                         preferred_element_type=jnp.int32) << shift)
-                else:
-                    _tag, ri, digits = term
-                    for i, j, s, w in digits:
-                        col = xp[..., ri * bk + i] << w
-                        acc = acc.at[..., j].add(col if s > 0 else -col)
-            pieces.append(acc)
-    return jnp.concatenate(pieces, axis=-1)[..., :out_cols]
+                pieces.append(acc)
+        tiles = jnp.concatenate(pieces, axis=-1)
+        out = tiles if out is None else out + tiles
+    return out[..., :out_cols]

@@ -9,7 +9,7 @@ compile-to-bitstream step) and fronts two fused implementations:
 
 * ``xla``    — a jitted ``lax.scan`` whose body does the *batched*
   recurrent multiply natively (dense or block-culled, dispatched on the
-  plan's block density) with the input projection hoisted into a single
+  plan's structure) with the input projection hoisted into a single
   (B*T, I) x (I, R) gemm before the scan.  The fast path on CPU/GPU.
 * ``pallas`` — the ``reservoir_rollout`` Pallas kernel fed by the plan's
   VMEM-banded layout: T steps fused in one launch, state resident in VMEM,
@@ -30,6 +30,7 @@ keeps the states contract, and the chunked schedulers drive
 from __future__ import annotations
 
 import collections
+import functools
 import time
 import warnings
 from typing import Sequence
@@ -45,7 +46,7 @@ from repro.kernels.reservoir_rollout.specialized import SpecializedRollout
 from repro.plan import (DEFAULT_BATCH_TILE, DEFAULT_VMEM_BUDGET, plan_for,
                         specialize_rollout)
 from repro.plan.autotune import resolve_backend, resolve_schedule
-from repro.plan.specialize import int8_recur_reference
+from repro.plan.specialize import int8_recur_reference, specialize_summary
 from repro.serve.api import (_UNSET, RolloutResult, SubmitSpec,
                              lifecycle_timings, warn_deprecated)
 from repro.serve.batching import MicroBatch, PaddingBucketer, RolloutRequest
@@ -146,10 +147,12 @@ class ReservoirEngine:
         self._dense_density = dense_dispatch_density
         self.uses_dense = (not self._int8 and
                            self.plan.block_density >= dense_dispatch_density)
-        # specialized int8: block-dense matrices take one folded int32
-        # gemm (the whole digit-plane fold), block-sparse ones the
-        # program's culled folded/shift-add schedule
+        # specialized int8, by the program's structure: a block-dense
+        # matrix whose program is all folded tiles takes one folded int32
+        # gemm (the whole digit-plane fold); the rest run the program's
+        # culled tiles plus its scattered table
         self._int8_dense = (self._int8 and specialize and
+                            self._int8_summary()["kind"] == "tiles" and
                             self.plan.block_density >= dense_dispatch_density)
         # trace-time tick per compiled rollout: the recompilation guard
         # (N chunks must trace once per shape/regime, never per chunk)
@@ -209,7 +212,7 @@ class ReservoirEngine:
             # planes into the quantized matrix — one int32 gemm replaces
             # the width shifted pos/neg plane products, bit-identically
             # (int32 accumulation is exact).  Block-sparse ones run the
-            # program's culled folded/shift-add schedule.
+            # program's culled folded tiles and scattered table.
             q_folded = w.q if self._int8_dense else None
             program = None
             if int8 and self.specialize and not self._int8_dense:
@@ -219,6 +222,10 @@ class ReservoirEngine:
                     batch_tile_max=self.batch_tile_max
                     or DEFAULT_BATCH_TILE)
         schedule = self.xla_schedule
+        # int8: the float products run at full f32 precision (the TPU's
+        # default rounds f32 operands to bfloat16, which the exact integer
+        # recurrence would carry on); fp32 keeps the default (ROADMAP 1.4)
+        prec = jax.lax.Precision.HIGHEST if int8 else None
 
         def rollout(u_bt: jnp.ndarray, x0: jnp.ndarray) -> jnp.ndarray:
             # trace-time side effect: the recompilation-guard counter
@@ -233,7 +240,8 @@ class ReservoirEngine:
             obs.inc("retrace_total" if n > 1 else "compile_traces_total",
                     backend="xla")
             # One gemm projects every input of every step before the scan.
-            uproj = u_bt.astype(jnp.float32) @ w_in          # (B, T, R)
+            uproj = jnp.matmul(u_bt.astype(jnp.float32), w_in,
+                               precision=prec)                # (B, T, R)
             uproj_t = jnp.swapaxes(uproj, 0, 1)              # (T, B, R)
 
             def body(x, up):
@@ -263,7 +271,7 @@ class ReservoirEngine:
                 # Fused readout: W_out applied inside the same compiled
                 # program — one dispatch, predictions only leave the device,
                 # and the result is the exact predict(states) contraction.
-                out = out @ w_out                            # (B, T, O)
+                out = jnp.matmul(out, w_out, precision=prec)  # (B, T, O)
             if with_final:
                 # xf is the scan carry — exactly x(T), so chunked rollouts
                 # that resume from it reproduce the one-shot trajectory
@@ -286,6 +294,46 @@ class ReservoirEngine:
         if self.specialize:
             return "int8-folded-culled"
         return "int8-planes"
+
+    @property
+    def lowering(self) -> str:
+        """What computes the recurrence: the XLA schedule, or ``pallas-``
+        with the program's kind and regime (``generic`` for the banded
+        kernel)."""
+        if self.backend != "pallas":
+            return self.xla_schedule
+        prog = self.program
+        if prog is None:
+            return "pallas-generic"
+        return f"pallas-{prog.kind}-{prog.regime}"
+
+    @functools.cached_property
+    def _recur_macs(self) -> int:
+        """Multiply-adds (digit adds included) the recurrence issues per
+        row and step, padding included: what the lowering computes, not
+        what the matrix needs."""
+        lowering, plan = self.lowering, self.plan
+        dim = self.config.reservoir_dim
+        tile = plan.block * plan.block
+        if lowering in ("fp32-dense", "int8-folded-dense"):
+            return dim * dim
+        if lowering in ("fp32-culled", "pallas-generic") and not self._int8:
+            return plan.blocks_nnz * tile
+        if lowering == "pallas-generic":
+            return plan.stats.int8_terms_kept * tile
+        if lowering == "int8-planes":
+            return 2 * plan.width * dim * dim      # pos and neg planes
+        if lowering.startswith("pallas-"):
+            prog = self.program
+            return prog.n_matmul_terms * tile + prog.shiftadd_digits
+        s = self._int8_summary()                   # culled tiles + table
+        return s["n_matmul_terms"] * tile + s["table_slots"]
+
+    def _int8_summary(self) -> dict:
+        """The counts of this engine's int8 program (kind, tiles, table);
+        none of them depends on the band budget."""
+        return specialize_summary(self.plan, "int8", vmem_budget=None,
+                                  crossover=self.crossover)
 
     @property
     def program(self):
@@ -379,14 +427,17 @@ class ReservoirEngine:
             seconds = time.perf_counter() - t0
             # padded rows count as executed-but-padded work, so
             # padding_efficiency stays honest about batch-tile padding
+            rows = self._executed_rows(batch)
             self.stats.record_call(
-                batch=self._executed_rows(batch), steps=steps,
+                batch=rows, steps=steps,
                 seconds=seconds, deferred=defer,
                 real_steps=batch * steps if real_steps is None
                 else real_steps)
             obs.span(_call_span(defer), t0, t0 + seconds,
                      backend=self.backend, batch=batch, steps=steps,
-                     deferred=defer)
+                     deferred=defer,
+                     recur_ops=self._recur_macs * rows * steps)
+            obs.inc("rollout_launches_total", lowering=self.lowering)
         return out
 
     def _resolve_want(self, want_states: bool | None) -> bool:

@@ -136,3 +136,19 @@ def test_xla_engine_rollout(one_chip, on_tpu):
     text = eng._local_rollout(True, True).lower(u, x0).compile().as_text()
     assert "tpu_custom_call" not in text        # plain XLA, no kernel
 
+
+def test_xla_scattered_table_rollout(one_chip, on_tpu):
+    """A degree-3 reservoir with one subdomain's inputs and outputs of
+    Pathak et al.'s parallel scheme (20 in, 8 out): the scattered table's
+    gather compiles for the chip, at a narrow and at the served pool's
+    width."""
+    cfg = ESNConfig(reservoir_dim=1280, element_sparsity=1 - 3 / 1280,
+                    input_dim=20, output_dim=8, mode="int8-csd")
+    eng = ReservoirEngine(_with_readout(_params(cfg)), backend="xla")
+    assert eng.xla_schedule == "int8-folded-culled"
+    assert eng._int8_summary()["kind"] == "scattered"
+    for rows in (8, 256):
+        u = _sds((rows, T, cfg.input_dim), one_chip)
+        x0 = _sds((rows, cfg.reservoir_dim), one_chip)
+        text = eng._local_rollout(True, True).lower(u, x0).compile().as_text()
+        assert "tpu_custom_call" not in text    # plain XLA, no kernel
