@@ -24,6 +24,7 @@ import heapq
 
 import jax
 import numpy as np
+from jax.sharding import NamedSharding, PartitionSpec
 
 from repro import obs
 from repro.dist.engine import ShardedReservoirEngine
@@ -76,6 +77,12 @@ class ShardedContinuousBatcher(ContinuousBatcher):
         """Keep a jitted pool op's result on the engine's shards."""
         return jax.lax.with_sharding_constraint(x, self.engine.batch_sharding)
 
+    def _place_stack(self, arrays):
+        """Replicate an admission stack over the mesh, so each shard's
+        write reads its own rows locally, with no collective."""
+        return jax.device_put(
+            arrays, NamedSharding(self.engine.mesh, PartitionSpec()))
+
     def shard_of(self, slot: int) -> int:
         return slot // self.slots_per_shard
 
@@ -121,6 +128,7 @@ class ShardedContinuousBatcher(ContinuousBatcher):
         """Freeze the in-flight work: ``(qreq, remaining_inputs, state,
         produced_chunks)`` per live slot — everything shrink needs to
         re-admit a sequence with nothing lost or recomputed."""
+        self.flush()
         states = np.asarray(self._states)
         out = []
         for i, q in enumerate(self._slots):

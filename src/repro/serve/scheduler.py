@@ -48,6 +48,25 @@ from repro.serve.api import (_UNSET, RolloutResult, SubmitSpec,
 from repro.serve.batching import RolloutRequest
 from repro.serve.stats import ServeStats
 
+# stack sizes of admission's pool write: a sweep's stack is padded up to
+# the next one, and a sweep seating more than the last writes several.
+# None is 1: the TPU compiler turns a one-row update of a sharded pool
+# into an all-gather of the whole buffer, where two rows stay local
+_STACKS = (2, 4, 8, 16)
+
+
+def write_stack(constrain, u_dev, states, idx, lanes, rows):
+    """Admission's pool write: stack entry ``j`` seats slot ``idx[j]`` with
+    state row ``rows[j]`` and, on the zero-copy pool (``u_dev`` not None),
+    input lanes ``lanes[j]``.  Out-of-range indices (stack padding) are
+    dropped.  ``constrain`` keeps each result where the pool lives."""
+    states = constrain(states.at[idx].set(
+        rows, mode="drop", unique_indices=True))
+    if u_dev is not None:
+        u_dev = constrain(u_dev.at[idx].set(
+            lanes, mode="drop", unique_indices=True))
+    return u_dev, states
+
 
 @dataclasses.dataclass
 class QueuedRequest:
@@ -188,32 +207,34 @@ class ContinuousBatcher:
         self._chunks: list[list] = [[] for _ in range(n_slots)]
         self._states = self._place(
             jnp.zeros((n_slots, self._dim), jnp.float32))
-        self._zero_row = jnp.zeros((self._dim,), jnp.float32)
         self._walked = 0                        # rider entries the syncs
         #                                         rewrote (read when traced)
+        # admissions seated but not yet written to the device:
+        # (slot, input copy or None, x0 row or None); flush() writes them
+        self._staged: list[tuple] = []
 
-        # the pool's own programs, named for the profiler's trace; each
-        # donated write keeps admission O(request) on accelerators instead
-        # of copying the whole pooled buffer
-        def pool_state_set(states, slot, row):
-            return self._constrain(states.at[slot].set(row))
+        # the pool's own programs, named for the profiler's trace.  The
+        # donated write seats a whole stack of admissions in place, so a
+        # sweep costs one transfer and one program per stack, not per
+        # request, and never copies the whole pooled buffer
+        def pool_write(u_dev, states, idx, lanes, rows):
+            return write_stack(self._constrain, u_dev, states, idx, lanes,
+                               rows)
 
         def pool_gather(u_dev, idx):
             return self._constrain(u_dev[jnp.arange(u_dev.shape[0]), idx])
 
-        def pool_lane_write(buf, slot, lanes):
-            return self._constrain(jax.lax.dynamic_update_slice(
-                buf, lanes[None], (slot, 0, 0, 0)))
-
-        self._state_set = jax.jit(pool_state_set, donate_argnums=(0,))
+        self._pool_write = jax.jit(pool_write, donate_argnums=(0, 1))
         self._max_chunks = 4                    # input lanes; doubles on
         #                                         demand (longer requests)
+        self._write_chunks = None               # lane count the write
+        #                                         stacks were compiled at
+        self._u_dev = None
         if zero_copy:
             self._u_dev = self._place(jnp.zeros(
                 (n_slots, self._max_chunks, chunk_steps, self._in_dim),
                 jnp.float32))
             self._gather = jax.jit(pool_gather)
-            self._lane_set = jax.jit(pool_lane_write, donate_argnums=(0,))
         self.last_take: dict = {}               # slot -> steps, last chunk
         self.last_retired_slots: list = []
         self.last_models: dict = {}             # slot -> model, last chunk
@@ -243,6 +264,12 @@ class ContinuousBatcher:
         result."""
         return x
 
+    def _place_stack(self, arrays):
+        """Send an admission stack to the device, in one call, where every
+        pool shard can read it (the default device; the sharded pool
+        replicates it over its mesh)."""
+        return jax.device_put(arrays)
+
     def _want_of(self, qreq: QueuedRequest) -> bool:
         return (self.want_states if qreq.want_states is None
                 else qreq.want_states)
@@ -263,23 +290,28 @@ class ContinuousBatcher:
 
         The batcher owns one static shape for its whole life, so every
         program it will ever run can compile at construction: the
-        (donated) chunk rollout, the input gather, and admission's
-        per-slot state seeding — none of it lands in the measured serving
-        makespan.  Bypasses the engine's public API so warmup never
-        pollutes ``ServeStats`` or the request telemetry.
+        (donated) chunk rollout, the input gather, and admission's pool
+        write at every stack size — none of it lands in the measured
+        serving makespan.  (A request longer than any before grows the
+        input lanes; the gather and the writes then compile once more.)
+        Bypasses the engine's public API so warmup never pollutes
+        ``ServeStats`` or the request telemetry.
         """
         if not self.want_states and not self.engine.has_readout:
             return      # run_chunk will raise the clear "readout not
             #             trained" error; nothing sane to warm
-        # admission's device ops: one warm call each compiles the program
-        # every slot index reuses (the index is an operand)
-        self._states = self._state_set(self._states, 0, self._zero_row)
+        self._warm_writes()
         if self.zero_copy:
             self._gather(self._u_dev, jnp.zeros(self.n_slots, jnp.int32))
-            self._u_dev = self._lane_set(
-                self._u_dev, 0,
-                jnp.zeros(self._u_dev.shape[1:], jnp.float32))
         self.warm_engine(self.engine)
+
+    def _warm_writes(self) -> None:
+        """Compile the pool write at every stack size for the current lane
+        count.  Each warm write's indices are all out of range, so it
+        changes nothing in the pool."""
+        for k in _STACKS:
+            self._write(*self._stack((), k))
+        self._write_chunks = self._max_chunks
 
     def warm_engine(self, engine, want_states: bool | None = None) -> None:
         """Compile ``engine``'s pool-shaped chunk program(s), off the
@@ -332,6 +364,11 @@ class ContinuousBatcher:
         through the ``resolver`` (registry routing, which also pins the
         model version on the request) or the pool default — and keeps it
         for the request's whole life.
+
+        The host bookkeeping is done at once, so free slots, quotas and
+        shard loads read true mid-sweep; the slot's device writes (its
+        input lanes and state row) are staged, copied off the caller's
+        buffers, for :meth:`flush`.
         """
         eng = (self.engine if self._resolver is None
                else self._resolver(qreq))
@@ -345,41 +382,81 @@ class ContinuousBatcher:
         self._slots[slot] = qreq
         self._pos[slot] = 0
         self._chunks[slot] = []
-        if self.zero_copy:
-            # ONE host->device transfer per request: the whole input,
-            # pre-cut into chunk_steps segments, lands in the slot's lane
-            # of the resident input buffer.  Lanes double when a request
-            # is longer than any seen before (shape change -> the gather
-            # re-specializes once, then stays cached).
-            cs = self.chunk_steps
-            seg = np.asarray(qreq.request.inputs, np.float32)
-            n_chunks = max(1, -(-seg.shape[0] // cs))
-            if n_chunks > self._max_chunks:
-                while n_chunks > self._max_chunks:
-                    self._max_chunks *= 2
-                # one reallocation straight to the final lane count
-                self._u_dev = self._place(jnp.zeros(
-                    (self.n_slots, self._max_chunks, cs, self._in_dim),
-                    jnp.float32).at[:, : self._u_dev.shape[1]].set(
-                        self._u_dev))
-            padded = np.zeros((self._max_chunks * cs,) + seg.shape[1:],
-                              np.float32)
-            padded[: seg.shape[0]] = seg
-            self._u_dev = self._lane_set(
-                self._u_dev, slot,
-                jnp.asarray(padded.reshape(self._max_chunks, cs, -1)))
         x0 = qreq.request.x0
-        row = (self._zero_row if x0 is None
-               else jnp.asarray(x0, jnp.float32))
-        self._states = self._state_set(self._states, slot, row)
+        self._staged.append((
+            slot,
+            np.array(qreq.request.inputs, np.float32)
+            if self.zero_copy else None,
+            None if x0 is None else np.array(x0, np.float32)))
         return slot
 
-    def admit_h2d_bytes(self, qreq: QueuedRequest) -> int:
-        """Bytes :meth:`admit` copied host->device for ``qreq``: its input
-        lanes on the zero-copy path, and its ``x0`` row if it has one."""
-        lanes = (self._max_chunks * self.chunk_steps * self._in_dim * 4
-                 if self.zero_copy else 0)
-        return lanes + (0 if qreq.request.x0 is None else self._dim * 4)
+    def flush(self) -> tuple[int, int]:
+        """Write every staged admission to the device.
+
+        Per stack of up to ``_STACKS[-1]`` seated slots: ONE host->device
+        transfer (indices, input lanes on the zero-copy path, state rows)
+        and ONE donated pool-write program, so a sweep's cost in device
+        calls does not grow with the requests it seats.  Each request's
+        whole input, pre-cut into ``chunk_steps`` segments, lands in its
+        slot's lane of the resident input buffer.  Lanes double when a
+        request is longer than any seen before, decided once per flush
+        (shape change -> the gather and the writes re-specialize once,
+        then stay cached).  Every reader of the pool buffers flushes
+        first.  Returns ``(programs issued, bytes sent host->device)``,
+        stack padding included.
+        """
+        if not self._staged:
+            return 0, 0
+        staged, self._staged = self._staged, []
+        if self.zero_copy:
+            self._grow_lanes(max(len(seg) for _s, seg, _x in staged))
+        if self._write_chunks != self._max_chunks:
+            self._warm_writes()
+        top = _STACKS[-1]
+        writes = nbytes = 0
+        for lo in range(0, len(staged), top):
+            part = staged[lo: lo + top]
+            stack = self._stack(
+                part, next(k for k in _STACKS if k >= len(part)))
+            nbytes += sum(a.nbytes for a in stack if a is not None)
+            self._write(*stack)
+            writes += 1
+        return writes, nbytes
+
+    def _grow_lanes(self, steps: int) -> None:
+        """Double the input lanes until a ``steps``-long request fits, with
+        one reallocation straight to the final lane count."""
+        cs = self.chunk_steps
+        n_chunks = max(1, -(-steps // cs))
+        if n_chunks <= self._max_chunks:
+            return
+        while n_chunks > self._max_chunks:
+            self._max_chunks *= 2
+        self._u_dev = self._place(jnp.zeros(
+            (self.n_slots, self._max_chunks, cs, self._in_dim),
+            jnp.float32).at[:, : self._u_dev.shape[1]].set(self._u_dev))
+
+    def _stack(self, part, k: int) -> tuple:
+        """Host arrays of one ``k``-entry pool write seating ``part``.
+        Padding entries carry distinct out-of-range indices, which the
+        write drops."""
+        idx = np.arange(self.n_slots, self.n_slots + k, dtype=np.int32)
+        rows = np.zeros((k, self._dim), np.float32)
+        lanes = (np.zeros((k, self._max_chunks, self.chunk_steps,
+                           self._in_dim), np.float32)
+                 if self.zero_copy else None)
+        for j, (slot, seg, x0) in enumerate(part):
+            idx[j] = slot
+            if x0 is not None:
+                rows[j] = x0
+            if lanes is not None:
+                lanes[j].reshape(-1, self._in_dim)[: len(seg)] = seg
+        return idx, lanes, rows
+
+    def _write(self, idx, lanes, rows) -> None:
+        idx, lanes, rows = self._place_stack((idx, lanes, rows))
+        self._u_dev, self._states = self._pool_write(
+            self._u_dev, self._states, idx, lanes, rows)
 
     def run_chunk(self) -> tuple[list[tuple[QueuedRequest, np.ndarray]], int]:
         """Roll every slot ``chunk_steps`` forward.
@@ -402,6 +479,7 @@ class ContinuousBatcher:
         byte-for-byte the old fast path: one call, donated carry on the
         zero-copy path.
         """
+        self.flush()
         cs = self.chunk_steps
         take: dict[int, int] = {}
         with obs.timed_span("scheduler.gather") as span:
@@ -598,6 +676,7 @@ class ContinuousBatcher:
         truth — the caller's host buffer was free to be reused the moment
         ``admit()`` uploaded it, so the elastic-shrink snapshot must NOT
         re-read it."""
+        self.flush()
         q = self._slots[slot]
         lo = self._pos[slot]
         if not self.zero_copy:
@@ -883,16 +962,17 @@ class AsyncReservoirServer:
 
     def _admit_arrived(self) -> None:
         with obs.timed_span("scheduler.admit") as span:
-            seated = self._admit_sweep()
+            seated, writes, h2d = self._admit_sweep()
             if span is not None:
-                span.attrs.update(
-                    admitted=len(seated),
-                    h2d_bytes=sum(map(self.batcher.admit_h2d_bytes, seated)))
+                span.attrs.update(admitted=len(seated), writes=writes,
+                                  h2d_bytes=h2d)
 
-    def _admit_sweep(self) -> list:
-        """Seat every arrived request the pool and quotas allow; returns
-        the seated.  A first seating of a request submitted while tracing
-        was on records its wall-clock ``request.wait`` from submit."""
+    def _admit_sweep(self) -> tuple[list, int, int]:
+        """Seat every arrived request the pool and quotas allow, then
+        write them to the device in one :meth:`ContinuousBatcher.flush`;
+        returns the seated, the pool-write programs and the bytes sent.
+        A first seating of a request submitted while tracing was on
+        records its wall-clock ``request.wait`` from submit."""
         seated = []
         held: list[tuple[float, int, QueuedRequest]] = []
         while self._queue and self._queue[0][0] <= self.now:
@@ -941,7 +1021,7 @@ class AsyncReservoirServer:
                     ts.record_admission(wait)
         for entry in held:
             heapq.heappush(self._queue, entry)
-        return seated
+        return (seated, *self.batcher.flush())
 
     # -- results -------------------------------------------------------------
     def _obs_labels(self, qreq: QueuedRequest, slot: int | None) -> dict:

@@ -267,6 +267,50 @@ class TestMultiDeviceServer:
         for s in range(8):
             assert cb.shard_stats[s].admitted == 1
 
+    def test_sweep_write_is_shard_local_and_least_loaded(self):
+        """The sharded pool write compiles to shard-local updates, and one
+        sweep seats least-loaded and writes every seated slot exactly."""
+        from repro.serve.scheduler import _STACKS
+        p = _params()
+        eng = ShardedReservoirEngine(p, n_shards=8, stats=ServeStats())
+        srv = DistributedReservoirServer(eng, slots_per_shard=2,
+                                         chunk_steps=4, chunk_time=1.0,
+                                         zero_copy=True, stats=ServeStats())
+        cb = srv.batcher
+        for k in _STACKS:
+            hlo = cb._pool_write.lower(
+                cb._u_dev, cb._states,
+                *cb._place_stack(cb._stack((), k))).compile().as_text()
+            for op in ("all-gather", "all-reduce", "collective-permute",
+                       "all-to-all", "reduce-scatter"):
+                assert op not in hlo, (k, op)
+        rng = np.random.default_rng(12)
+        specs = [SubmitSpec(
+            rng.standard_normal((3 + 2 * i, 1)).astype(np.float32), uid=i,
+            x0=rng.standard_normal(96).astype(np.float32) if i % 3 else None)
+            for i in range(8)]
+        for s in specs:
+            srv.submit(s)
+        srv._admit_arrived()
+        assert cb.free_slots_by_shard() == [1] * 8
+        for s in range(8):
+            assert cb.shard_stats[s].admitted == 1
+        for buf in (cb._u_dev, cb._states):
+            assert buf.sharding.is_equivalent_to(eng.batch_sharding,
+                                                 buf.ndim)
+        lanes, states = np.asarray(cb._u_dev), np.asarray(cb._states)
+        seated = {q.uid: i for i, q in enumerate(cb._slots) if q is not None}
+        assert sorted(seated) == list(range(8))
+        want_lanes = np.zeros_like(lanes)
+        want_states = np.zeros_like(states)
+        for s in specs:
+            slot = seated[s.uid]
+            want_lanes[slot].reshape(-1, 1)[: len(s.inputs)] = s.inputs
+            if s.x0 is not None:
+                want_states[slot] = s.x0
+        np.testing.assert_array_equal(lanes, want_lanes)
+        np.testing.assert_array_equal(states, want_states)
+
     def test_results_match_single_device(self):
         p = _params()
         eng = ShardedReservoirEngine(p, n_shards=8, stats=ServeStats())

@@ -318,7 +318,8 @@ def _check_phase_spans(tracer, chunks: int, engine_span: str) -> None:
     for s in tracer.spans(name="scheduler.retire"):
         assert set(s.attrs) == {"retired", "entries_walked"}
     admits = tracer.spans(name="scheduler.admit")
-    assert all(set(s.attrs) == {"admitted", "h2d_bytes"} for s in admits)
+    assert all(set(s.attrs) == {"admitted", "writes", "h2d_bytes"}
+               for s in admits)
     assert {s.parent for s in tracer.spans(name="request.wait")} == {
         "scheduler.admit"}
 
@@ -346,13 +347,35 @@ class TestPhaseSpans:
         retired = sum(s.attrs["retired"]
                       for s in tr.spans(name="scheduler.retire"))
         assert admitted == retired == 6
-        # the zero-copy pool uploads each request's lanes at admission
-        h2d = sum(s.attrs["h2d_bytes"]
-                  for s in tr.spans(name="scheduler.admit"))
-        assert (h2d > 0) == zero_copy
+        # each sweep that seats anything sends one stack, padded to a
+        # power of two: indices and state rows, and on the zero-copy pool
+        # each request's input lanes too
+        cb = srv.batcher
+        lane = (cb._max_chunks * cb.chunk_steps * cb._in_dim * 4
+                if zero_copy else 0)
+        for s in tr.spans(name="scheduler.admit"):
+            n = s.attrs["admitted"]
+            k = next(s for s in scheduler._STACKS if s >= n) if n else 0
+            assert s.attrs["writes"] == (n > 0)
+            assert s.attrs["h2d_bytes"] == k * (4 + 4 * cb._dim + lane)
         assert len(tr.spans(name="scheduler.sync")) == \
             srv.batcher.host_syncs
         assert len(tr.spans(name="request.wait")) == 6
+
+    @pytest.mark.parametrize("k", [5, 16, 37])
+    def test_sweep_span_counts_its_stacks(self, k):
+        """One sweep seating ``k`` requests reports them all and one
+        pool-write program per stack of up to 16."""
+        obs.configure()
+        eng = ReservoirEngine(_params(), backend="xla", stats=ServeStats())
+        srv = AsyncReservoirServer(eng, n_slots=40, chunk_steps=8,
+                                   zero_copy=True)
+        for i in range(k):
+            srv.submit(SubmitSpec(np.ones((12, 1), np.float32), uid=i))
+        srv.step()
+        (span,) = obs.tracer().spans(name="scheduler.admit")
+        assert span.attrs["admitted"] == k
+        assert span.attrs["writes"] == -(-k // 16)
 
     def test_disabled_reads_no_clock_and_builds_no_annotation(
             self, monkeypatch):

@@ -386,6 +386,135 @@ class TestRecompilationGuard:
         assert sum(eng.trace_counts.values()) == 2
 
 
+    @pytest.mark.parametrize("zero_copy", [False, True])
+    def test_steady_state_compiles_no_new_write(self, zero_copy):
+        """Construction compiles the pool write at every stack size; a
+        server seating sweeps of every size compiles none after that,
+        and a lane growth compiles each size once more."""
+        from repro.serve.scheduler import _STACKS
+        p = _params()
+        _, srv = _server(p, n_slots=24, chunk_steps=4, zero_copy=zero_copy)
+        write = srv.batcher._pool_write
+        assert write._cache_size() == len(_STACKS)
+        rng = np.random.default_rng(14)
+        t = 0.0
+        for burst in (1, 2, 3, 5, 9, 16, 24, 4):
+            for _ in range(burst):
+                srv.submit(SubmitSpec(rng.standard_normal(
+                    (int(rng.integers(1, 17)), 1)).astype(np.float32)),
+                    arrival_time=t)
+            t += 6.0
+        srv.run()
+        assert srv.stats.completed == 64
+        assert write._cache_size() == len(_STACKS)
+        srv.submit(SubmitSpec(np.ones((40, 1), np.float32)))
+        srv.run()
+        assert write._cache_size() == len(_STACKS) * (1 + zero_copy)
+
+
+def _qreq(uid, steps, x0=None, seed=0):
+    from repro.serve.scheduler import QueuedRequest
+    rng = np.random.default_rng(seed)
+    return QueuedRequest(RolloutRequest(
+        uid=uid, inputs=rng.standard_normal((steps, 1)).astype(np.float32),
+        x0=x0))
+
+
+class TestBatchedAdmission:
+    """A sweep's admissions are written to the device in stacks, one
+    transfer and one pool-write program per stack: the pool must come out
+    bit-identical to seating and writing each request on its own."""
+
+    @staticmethod
+    def _pool(cb):
+        lanes = None if cb._u_dev is None else np.asarray(cb._u_dev)
+        return lanes, np.asarray(cb._states)
+
+    @pytest.mark.parametrize("zero_copy", [False, True])
+    @pytest.mark.parametrize("with_x0", [False, True])
+    @pytest.mark.parametrize("grow", [False, True])
+    @pytest.mark.parametrize("k", [1, 3, 8, 17, 40])
+    def test_sweep_pool_equals_per_request_writes(self, k, grow, with_x0,
+                                                  zero_copy):
+        p = _params()
+        cs, dim, resident = 4, 96, 2
+        rng = np.random.default_rng(k)
+        # the sweep's middle request outgrows the 4 starting lanes
+        lengths = [int(rng.integers(1, 4 * cs + 1)) for _ in range(k)]
+        if grow:
+            lengths[k // 2] = 9 * cs + 1
+        x0s = [rng.standard_normal(dim).astype(np.float32)
+               if with_x0 and i % 2 == 0 else None for i in range(k)]
+
+        def seat(per_request):
+            eng = ReservoirEngine(p, stats=ServeStats())
+            cb = ContinuousBatcher(eng, n_slots=resident + k + 3,
+                                   chunk_steps=cs, zero_copy=zero_copy,
+                                   warm=False)
+            for i in range(resident):
+                cb.admit(_qreq(f"r{i}", 6, x0=np.full(dim, 0.5, np.float32),
+                               seed=100 + i))
+            cb.flush()
+            writes = 0
+            for i in range(k):
+                cb.admit(_qreq(i, lengths[i], x0=x0s[i], seed=i))
+                if per_request:
+                    writes += cb.flush()[0]
+            writes += cb.flush()[0]
+            assert cb.flush() == (0, 0)         # nothing left staged
+            return cb, writes
+
+        alone, n_alone = seat(per_request=True)
+        swept, n_swept = seat(per_request=False)
+        assert n_alone == k and n_swept == -(-k // 16)
+        (lanes_a, states_a), (lanes_s, states_s) = map(
+            self._pool, (alone, swept))
+        np.testing.assert_array_equal(states_s, states_a)
+        # the host reference: each seated slot holds its x0 (or zeros)
+        # and its zero-padded input, every other slot is untouched
+        want = np.zeros_like(states_s)
+        want[:resident] = 0.5
+        for i, x0 in enumerate(x0s):
+            if x0 is not None:
+                want[resident + i] = x0
+        np.testing.assert_array_equal(states_s, want)
+        if not zero_copy:
+            assert lanes_s is None and lanes_a is None
+            return
+        np.testing.assert_array_equal(lanes_s, lanes_a)
+        assert swept._max_chunks == (16 if grow else 4)
+        for i in range(k):
+            flat = lanes_s[resident + i].reshape(-1, 1)
+            np.testing.assert_array_equal(
+                flat[: lengths[i]], _qreq(i, lengths[i], seed=i).request.inputs)
+            assert not flat[lengths[i]:].any()
+        assert not lanes_s[resident + k:].any()
+
+    @pytest.mark.parametrize("zero_copy", [False, True])
+    def test_lone_admit_then_run_chunk(self, zero_copy):
+        """A direct ``admit`` stays staged until the pool is read:
+        ``run_chunk`` writes it first and rolls it from its ``x0``."""
+        p = _params()
+        eng = ReservoirEngine(p, stats=ServeStats())
+        cb = ContinuousBatcher(eng, n_slots=2, chunk_steps=8,
+                               zero_copy=zero_copy)
+        x0 = np.full((96,), 0.3, np.float32)
+        q = _qreq("a", 8, x0=x0, seed=3)
+        u = q.request.inputs.copy()
+        assert cb.admit(q) == 0
+        assert len(cb._staged) == 1
+        if zero_copy:
+            q.request.inputs[:] = 999.0     # copied at admit, not at write
+        (qr, out), = cb.run_chunk()[0]
+        assert qr.uid == "a" and not cb._staged
+        batch = np.zeros((2, 8, 1), np.float32)
+        batch[0] = u
+        x0s = np.zeros((2, 96), np.float32)
+        x0s[0] = x0
+        want, _ = eng.run_segment(jnp.asarray(batch), jnp.asarray(x0s))
+        np.testing.assert_array_equal(out, np.asarray(want)[0])
+
+
 class TestZeroCopyServing:
     def test_host_syncs_only_at_retirement(self):
         """The zero-copy hot loop defers every device->host transfer to

@@ -16,7 +16,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from jax.sharding import SingleDeviceSharding
+from jax.sharding import (Mesh, NamedSharding, PartitionSpec,
+                          SingleDeviceSharding)
 
 from repro.configs.esn_paper import LARGE_1024
 from repro.core.esn import ESNConfig, init_esn
@@ -24,19 +25,24 @@ from repro.kernels.reservoir_rollout.ops import FusedRollout
 from repro.kernels.reservoir_rollout.specialized import SpecializedRollout
 from repro.plan import plan_for
 from repro.serve.engine import ReservoirEngine
+from repro.serve.scheduler import _STACKS, write_stack
 
 T = 16                      # chunk_steps of the served pool
 
 
 @pytest.fixture(scope="module")
-def one_chip():
+def topo():
     os.environ.setdefault("TPU_LOG_DIR", "disabled")
     from jax.experimental import topologies
     try:
-        topo = topologies.get_topology_desc(platform="tpu",
+        return topologies.get_topology_desc(platform="tpu",
                                             topology_name="v5e:2x2")
     except Exception as e:          # no TPU compiler in this installation
         pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
     return SingleDeviceSharding(topo.devices[0])
 
 
@@ -152,3 +158,28 @@ def test_xla_scattered_table_rollout(one_chip, on_tpu):
         x0 = _sds((rows, cfg.reservoir_dim), one_chip)
         text = eng._local_rollout(True, True).lower(u, x0).compile().as_text()
         assert "tpu_custom_call" not in text    # plain XLA, no kernel
+
+
+def test_sharded_pool_write_is_shard_local(topo):
+    """Admission's pool write over a four-chip pool of the served shape
+    (256 slots per chip, 256 input lanes): at every stack size each chip
+    updates its own slots, with no collective.  (A one-row stack is not
+    among the sizes: the compiler gathers the whole pool for it.)"""
+    mesh = Mesh(np.array(topo.devices[:4]), ("data",))
+    pool = NamedSharding(mesh, PartitionSpec("data"))
+    stack = NamedSharding(mesh, PartitionSpec())
+    lanes = (256, T, LARGE_1024.input_dim)
+    write = jax.jit(lambda *a: write_stack(
+        lambda x: jax.lax.with_sharding_constraint(x, pool), *a),
+        donate_argnums=(0, 1))
+    for k in _STACKS:
+        text = write.lower(
+            _sds((1024,) + lanes, pool),
+            _sds((1024, LARGE_1024.reservoir_dim), pool),
+            jax.ShapeDtypeStruct((k,), jnp.int32, sharding=stack),
+            _sds((k,) + lanes, stack),
+            _sds((k, LARGE_1024.reservoir_dim), stack)).compile().as_text()
+        assert "scatter" in text
+        for op in ("all-gather", "all-reduce", "collective-permute",
+                   "all-to-all", "reduce-scatter"):
+            assert op not in text, (k, op)
