@@ -36,8 +36,6 @@ FAST = False                      # --fast: smaller sweeps for CI smoke runs
 JSON_OUT = "BENCH_serve.json"     # --json-out: serve-family results
 STATS_OUT = "BENCH_plan_stats.json"  # plan-compiler stats (CI culling gate)
 SPECIALIZE_OUT = "BENCH_specialize.json"  # regime-selection stats artifact
-AUTOTUNE_CACHE_OUT = "AUTOTUNE_cache.json"  # measured schedule winners
-AUTOTUNE_CALIB_OUT = "AUTOTUNE_calibration.json"  # refit cost coefficients
 OBS_OUT = "BENCH_obs.json"        # observability overhead gate artifact
 SUSTAINED_OUT = "BENCH_sustained.json"  # sustained-load SLO gate artifact
 OBS_PROM_OUT = "OBS_metrics.prom"    # Prometheus scrape payload artifact
@@ -816,18 +814,10 @@ def serve_specialized():
     the fused rollout PR 2 shipped.  Regime-selection stats (resident vs
     double-buffered, on-chip bytes, matmul vs shift-add term counts) land
     in BENCH_specialize.json for the CI artifact.
-
-    Each row also runs the schedule autotuner (predict -> prune -> measure
-    over the same workload) and records the chosen schedule next to the
-    default-heuristic numbers.  ``autotune_speedup`` is the ratio of the
-    default schedule's measured time to the winner's, taken from the
-    tuner's own trials — the default is always among the measured
-    candidates and the winner is the measured argmin, so the ratio is
-    >= 1.0 by construction, which is what CI gates.
     """
     import jax
     import jax.numpy as jnp
-    from repro.plan import autotune_rollout, plan_for, specialize_summary
+    from repro.plan import plan_for, specialize_summary
     from repro.serve import ReservoirEngine
 
     dims = (256, 512) if FAST else (512, 1024, 2048)
@@ -839,8 +829,7 @@ def serve_specialized():
         params = _serve_params(dim, mode)
         baseline = ReservoirEngine(params, specialize=False)
         # backend pinned: this row measures the *default-heuristic*
-        # specialized program; backend="auto" would resolve through the
-        # tuner and blur the comparison the autotune columns make.
+        # specialized program on XLA, whatever the schedule rule picks.
         spec = ReservoirEngine(params, backend="xla")
         rng = np.random.default_rng(6)
         u = jnp.asarray(rng.standard_normal((batch, t_steps, 4)), jnp.float32)
@@ -855,18 +844,9 @@ def serve_specialized():
         steps = batch * t_steps
         speedup = t_base / t_spec
         plan = plan_for(params.w)
-        tuned = autotune_rollout(plan, "int8", batch=batch, steps=t_steps,
-                                 params=params, reps=reps)
-        tuned_eng = ReservoirEngine(params, schedule=tuned)
-        assert (ref == np.asarray(tuned_eng.rollout(u[:2, :2]))).all(), \
-            f"autotuned != baseline at dim {dim}"
-        t_tuned = _time_rollout(
-            lambda: jax.block_until_ready(tuned_eng.rollout(u)), reps)
-        autotune_speedup = tuned.default_measured_s / tuned.measured_s
         regime = specialize_summary(plan, "int8")
         regime["fp32"] = specialize_summary(plan, "fp32")
         regime["xla_schedule"] = spec.xla_schedule
-        regime["autotune"] = tuned.as_dict()
         SPECIALIZE_STATS[f"serve_{dim}_{mode}"] = regime
         emit(f"serve_specialized/{mode}/dim={dim}/batch={batch}/baseline",
              t_base * 1e6 / steps, f"steps_per_sec={steps / t_base:.0f}")
@@ -874,11 +854,6 @@ def serve_specialized():
              t_spec * 1e6 / steps,
              f"steps_per_sec={steps / t_spec:.0f};speedup={speedup:.2f};"
              f"regime={regime['regime']}")
-        emit(f"serve_specialized/{mode}/dim={dim}/batch={batch}/autotuned",
-             t_tuned * 1e6 / steps,
-             f"steps_per_sec={steps / t_tuned:.0f};"
-             f"autotune_speedup={autotune_speedup:.2f};"
-             f"schedule={tuned.schedule.describe()}")
         SERVE_RESULTS.append({
             "family": "serve_specialized",
             "mode": mode, "dim": dim, "batch": batch,
@@ -891,11 +866,6 @@ def serve_specialized():
             "resident_bytes": regime["resident_bytes"],
             "n_matmul_terms": regime["n_matmul_terms"],
             "n_shiftadd_terms": regime["n_shiftadd_terms"],
-            "autotune_schedule": tuned.schedule.as_dict(),
-            "autotune_speedup": autotune_speedup,
-            "autotuned_steps_per_sec": steps / t_tuned,
-            "autotune_predicted_s": tuned.predicted_s,
-            "autotune_measured_s": tuned.measured_s,
         })
     # Pallas datapoint: specialized kernel (resident/pipelined regime,
     # batch-tiled) vs the generic banded kernel, interpret mode on CPU —
@@ -911,104 +881,6 @@ def serve_specialized():
     emit("serve_specialized/fp32/dim=256/batch=8/pallas_interpret",
          t_sp * 1e6 / 64,
          f"generic_us={t_gen * 1e6 / 64:.1f};regime={sp.program.regime}")
-
-
-def serve_autotune():
-    """Closing the loop on the cost model: predict -> prune -> measure.
-
-    For each serving matrix, report the schedule the tuner chose, its
-    predicted vs measured cost (the analytic model's calibration error on
-    the point that matters), then refit the cost-model coefficients from
-    *all* measured trials and report how much calibration shrinks the
-    error.  Two artifacts ride along for CI:
-
-    * ``AUTOTUNE_cache.json`` — the measured winners keyed on
-      ``(plan fingerprint, mode, batch bucket, hardware)``, so a serve
-      process loads them at startup and never re-tunes.
-    * ``AUTOTUNE_calibration.json`` — refit coefficients plus
-      prior-vs-fit relative error, the evidence the loop converges.
-
-    Runs after ``serve_specialized``, whose tuner calls already populated
-    the process cache — resolution here is a cache hit replaying the
-    measured trials, not a second round of measurement.
-    """
-    import jax
-    from repro.plan import (Schedule, autotune_cache_save, autotune_rollout,
-                            plan_for, specialize_summary)
-
-    dims = (256, 512) if FAST else (512, 1024, 2048)
-    batch = 8
-    t_steps = 4 if FAST else 8
-    mode = "int8-csd"
-    platform = jax.default_backend()
-    samples, rows = [], []
-    for dim in dims:
-        params = _serve_params(dim, mode)
-        plan = plan_for(params.w)
-        tuned = autotune_rollout(plan, "int8", batch=batch, steps=t_steps,
-                                 params=params, reps=2)
-        steps = batch * t_steps
-        for sd, pred, meas in tuned.trials:
-            s = Schedule.from_dict(sd)
-            feats = costmodel.rollout_cost_features(
-                specialize_summary(plan, s.mode, vmem_budget=s.vmem_budget,
-                                   crossover=s.crossover,
-                                   batch_tile_max=s.batch_tile_max),
-                plan.block, batch, t_steps, s.backend)
-            samples.append((s.backend, feats, meas))
-        rel_err = (abs(tuned.predicted_s - tuned.measured_s)
-                   / tuned.measured_s)
-        autotune_speedup = tuned.default_measured_s / tuned.measured_s
-        row = {
-            "family": "serve_autotune",
-            "mode": mode, "dim": dim, "batch": batch, "steps": t_steps,
-            "hardware": platform,
-            "schedule": tuned.schedule.as_dict(),
-            "n_candidates": tuned.n_candidates,
-            "n_measured": len(tuned.trials),
-            "predicted_s": tuned.predicted_s,
-            "measured_s": tuned.measured_s,
-            "default_predicted_s": tuned.default_predicted_s,
-            "default_measured_s": tuned.default_measured_s,
-            "autotune_speedup": autotune_speedup,
-            "prediction_rel_err": rel_err,
-            "steps_per_sec": steps / tuned.measured_s,
-        }
-        rows.append(row)
-        SPECIALIZE_STATS[f"autotune_{dim}_{mode}"] = dict(
-            row,
-            trials=[{"schedule": sd, "predicted_s": p, "measured_s": m}
-                    for sd, p, m in tuned.trials])
-        emit(f"serve_autotune/{mode}/dim={dim}/batch={batch}/tuned",
-             tuned.measured_s * 1e6 / steps,
-             f"steps_per_sec={steps / tuned.measured_s:.0f};"
-             f"autotune_speedup={autotune_speedup:.2f};"
-             f"pred_rel_err={rel_err:.2f};"
-             f"schedule={tuned.schedule.describe()}")
-    SERVE_RESULTS.extend(rows)
-    # refit the analytic model from the measured trials: the calibration
-    # artifact is what turns the shipped priors into this machine's model
-    fitted = costmodel.fit_rollout_cost(samples, platform=platform)
-    prior = costmodel.default_rollout_cost_model(platform)
-    err_prior = [abs(prior.predict(bk, f) - y) / y for bk, f, y in samples]
-    err_fit = [abs(fitted.predict(bk, f) - y) / y for bk, f, y in samples]
-    calib = {
-        "platform": platform,
-        "n_samples": len(samples),
-        "mean_rel_err_prior": float(np.mean(err_prior)),
-        "mean_rel_err_fit": float(np.mean(err_fit)),
-        "model": fitted.as_dict(),
-    }
-    with open(AUTOTUNE_CALIB_OUT, "w") as fh:
-        json.dump(calib, fh, indent=2, sort_keys=True)
-    autotune_cache_save(AUTOTUNE_CACHE_OUT)
-    print(f"# wrote {AUTOTUNE_CACHE_OUT} + {AUTOTUNE_CALIB_OUT} "
-          f"(fit err {calib['mean_rel_err_fit']:.2f} vs prior "
-          f"{calib['mean_rel_err_prior']:.2f} over {len(samples)} trials)",
-          file=sys.stderr)
-    emit(f"serve_autotune/calibration/n={len(samples)}",
-         calib["mean_rel_err_fit"],
-         f"prior_rel_err={calib['mean_rel_err_prior']:.2f}")
 
 
 def serve_registry():
@@ -1278,10 +1150,6 @@ def _flush_serve_json():
                                  "propagated CSD folding, resident/"
                                  "pipelined regimes) vs the PR-2 fused "
                                  "baseline",
-            "serve_autotune": "schedule autotuner: predicted vs measured "
-                              "cost of the chosen schedule per matrix, "
-                              "plus cost-model recalibration from the "
-                              "measured trials",
             "serve_registry": "multi-tenant registry serving: cross-"
                               "tenant p99 vs single-tenant on one pool, "
                               "and publish() live-swap cost behind "
@@ -1324,8 +1192,7 @@ ALL = [fig05_bit_sparsity, fig06_element_vs_bit_sparse, fig07_matrix_size,
        fig17_18_batching, fig19_20_sigma_dim, fig21_22_sigma_sparsity,
        fig23_sigma_batching, esn_quality, kernel_walltimes, serve_rollout,
        serve_readout, serve_queue, serve_sharded, serve_specialized,
-       serve_autotune, serve_registry, serve_obs, serve_sustained,
-       serve_plan_stats]
+       serve_registry, serve_obs, serve_sustained, serve_plan_stats]
 
 
 def main(argv=None) -> None:

@@ -238,8 +238,9 @@ def sharded_phase(params: ESNParams, inputs: list, *, n_shards: int,
     sharded = _serve(server, inputs)
     retraces = sum(engine.trace_counts.values()) - warm
 
-    # the same resolved schedule, so both sides run the same program
-    single_engine = ReservoirEngine(params, schedule=engine.schedule)
+    # the rule picks the same schedule for the same params, so both sides
+    # run the same program
+    single_engine = ReservoirEngine(params)
     single = _serve(AsyncReservoirServer(single_engine, n_slots=n_slots,
                                          chunk_steps=chunk_steps), inputs)
     mismatched = [i for i, (a, b) in enumerate(zip(sharded, single))

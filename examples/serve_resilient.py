@@ -4,8 +4,8 @@ Two acts on the deterministic virtual clock:
 
 1. **Overload + backpressure** (single device): a 3x-oversubscribed
    Poisson trace hits an :class:`~repro.serve.admission.AdmissionPolicy`
-   stack — bounded queue depth, deadline shedding off the calibrated
-   cost model's queue-delay estimate — and every refusal is an explicit
+   stack — bounded queue depth, deadline shedding off the estimated
+   queue delay — and every refusal is an explicit
    ``RolloutResult(status="rejected")`` with a retry-after hint, while
    the admitted requests keep a bounded p99.  The same trace without
    admission control shows the unbounded queue's latency blow-up.

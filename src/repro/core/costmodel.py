@@ -20,13 +20,9 @@ from __future__ import annotations
 import dataclasses
 import math
 
-import numpy as np
-
 __all__ = [
     "XCVU13P",
     "FPGADesignPoint",
-    "ROLLOUT_FEATURES",
-    "RolloutCostModel",
     "expected_ones",
     "luts_for_ones",
     "ffs_for_ones",
@@ -35,9 +31,6 @@ __all__ = [
     "latency_cycles",
     "design_point",
     "tpu_decode_bytes",
-    "rollout_cost_features",
-    "default_rollout_cost_model",
-    "fit_rollout_cost",
 ]
 
 # --- Xilinx XCVU13P (paper Sec. VI) ---------------------------------------
@@ -183,171 +176,6 @@ def design_point(
         power_w=power_w(ones, f),
         cycles=latency_cycles(input_bits, weight_bits, rows),
     )
-
-
-# --- Rollout schedule cost model (plan autotuning) -------------------------
-# The same "simple and extensible" philosophy as the FPGA model above,
-# pointed at the TPU/CPU rollout: a specialized RolloutProgram's runtime is
-# a linear combination of the work terms its schedule implies.  The
-# autotuner (repro.plan.autotune) prices every candidate schedule with
-# these coefficients, prunes, then measures the survivors — and
-# ``fit_rollout_cost`` closes the loop by refitting the coefficients from
-# the measured rows, so the prior below only has to get the *ordering*
-# roughly right, never the absolute seconds.
-
-ROLLOUT_FEATURES = (
-    "matmul_macs",     # folded-tile MAC count across the whole rollout
-    "shiftadd_ops",    # digit adds below the crossover across the rollout
-    "stream_bytes",    # weight bytes moved (once if resident, per step if
-                       # pipelined or on XLA — the regime axis of the search)
-    "band_steps",      # band-grid iterations (per-band launch overhead)
-    "tile_steps",      # batch-tile-grid iterations (per-tile overhead)
-    "steps",           # scan/grid steps (per-step dispatch overhead)
-)
-
-
-def rollout_cost_features(summary: dict, block: int, batch: int,
-                          steps: int = 1, backend: str | None = None) -> dict:
-    """Work terms of one specialized schedule over a ``(batch, steps)``
-    rollout, computed from :func:`~repro.plan.specialize.specialize_summary`
-    counts only — no tile data is ever materialized to price a candidate.
-
-    ``backend="xla"`` prices the folded tiles as XLA reads them: its scan
-    keeps no weight on chip from one step to the next, so they stream
-    every step whatever the regime.
-    """
-    batch_tile_max = summary.get("batch_tile_max", 16)
-    n_tiles = max(1, -(-batch // batch_tile_max))
-    b_tile = -(-batch // n_tiles)
-    b_pad = b_tile * n_tiles
-    itemsize = 4 if summary["mode"] == "fp32" else 1
-    tile_bytes = block * block * itemsize
-    payload = summary["n_matmul_terms"] * tile_bytes
-    if summary["regime"] == "resident" and backend != "xla":
-        stream = payload                       # hoisted on-chip once
-    else:
-        stream = payload * steps               # re-streamed every step
-    return {
-        "matmul_macs": summary["n_matmul_terms"] * block * block
-        * b_pad * steps,
-        "shiftadd_ops": summary["shiftadd_digits"] * b_pad * steps,
-        "stream_bytes": stream,
-        "band_steps": summary["n_bands"] * steps,
-        "tile_steps": summary["n_bands"] * n_tiles * steps,
-        "steps": steps,
-    }
-
-
-@dataclasses.dataclass
-class RolloutCostModel:
-    """Per-backend linear model over :data:`ROLLOUT_FEATURES` + intercept.
-
-    ``coeffs[backend]`` is an ndarray of ``len(ROLLOUT_FEATURES) + 1``
-    seconds-per-unit weights (intercept last).  Coefficients come from
-    :func:`default_rollout_cost_model` (platform prior) or
-    :func:`fit_rollout_cost` (calibrated against measured bench rows).
-    """
-
-    coeffs: dict
-    platform: str = "cpu"
-
-    def predict(self, backend: str, features: dict) -> float:
-        c = self.coeffs.get(backend)
-        if c is None:
-            raise KeyError(f"no coefficients for backend {backend!r} "
-                           f"(have {sorted(self.coeffs)})")
-        v = np.array([features[k] for k in ROLLOUT_FEATURES] + [1.0])
-        return float(v @ np.asarray(c))
-
-    def as_dict(self) -> dict:
-        return {"platform": self.platform,
-                "features": list(ROLLOUT_FEATURES) + ["intercept"],
-                "coeffs": {bk: [float(x) for x in c]
-                           for bk, c in self.coeffs.items()}}
-
-    @classmethod
-    def from_dict(cls, d: dict) -> "RolloutCostModel":
-        return cls(coeffs={bk: np.asarray(c, np.float64)
-                           for bk, c in d["coeffs"].items()},
-                   platform=d.get("platform", "cpu"))
-
-
-def default_rollout_cost_model(platform: str = "cpu") -> RolloutCostModel:
-    """Platform prior for the rollout cost model.
-
-    calibrated: the absolute values are napkin numbers (CPU gemm tens of
-    GFLOP/s, TPU MXU hundreds of TOP/s int8, HBM at the roofline's 819
-    GB/s); what the autotuner's pruning needs is only that the *relative*
-    cost of regimes/backends is right.  On non-TPU platforms the pallas
-    kernels run in interpret mode, so its per-term coefficients carry an
-    interpreter penalty large enough that pallas never survives pruning
-    off-TPU — preserving the XLA-first dispatch the serve tests pin.
-    XLA computes the digits below the crossover from the scattered table,
-    Pallas unrolls them: each backend's ``shiftadd_ops`` term prices its
-    own way.
-
-    measured (TPU v5e, one chip), XLA's terms: the int8 dense fold of a
-    5000-node matrix took 0.64 / 0.67 / 0.80 ms per 16-step launch of 8 /
-    64 / 256 rows, so it streams the folded weights every step (1.48e-12
-    s/B) and multiplies at 1.6e-15 s/MAC; the scattered table of the same
-    matrix (43,446 digits) took 1.77 ms at 8 rows, 3.2e-10 s per
-    digit-row-step at the tuning batch (the gather is nearly flat in the
-    rows, so wider batches cost less per row than this prices).  Pallas's
-    MAC term: the dim-1024 matrix's 64 resident tiles took 0.32 ms per
-    256-row, 16-step launch (7.4e-14 s/MAC); its shift-add and overhead
-    terms are priors (the unroll that would measure them is dropped by the
-    autotuner's VMEM filter wherever it is large).
-    """
-    if platform == "tpu":
-        coeffs = {
-            #        macs    shiftadd stream   band  tile  step  icept
-            "xla": [1.6e-15, 3.2e-10, 1.48e-12, 1e-7, 2e-8, 5e-7, 2e-5],
-            # fused grid: no per-step dispatch back to the host
-            "pallas": [7.4e-14, 2e-12, 1.3e-12, 5e-8, 1e-8, 2e-8, 1e-5],
-        }
-    else:
-        coeffs = {
-            "xla": [2e-11, 1e-9, 2e-11, 2e-6, 1e-6, 2e-6, 1e-4],
-            # interpret-mode pallas: every grid step is python dispatch
-            "pallas": [2e-9, 2e-7, 2e-9, 1e-3, 1e-3, 1e-2, 1e-2],
-        }
-    return RolloutCostModel(
-        coeffs={bk: np.asarray(c, np.float64) for bk, c in coeffs.items()},
-        platform=platform)
-
-
-def fit_rollout_cost(samples, platform: str = "cpu") -> RolloutCostModel:
-    """Calibrate the cost model from measured rows.
-
-    ``samples``: iterable of ``(backend, features_dict, measured_seconds)``
-    — the autotuner's measured trials, or rows replayed from
-    ``BENCH_specialize.json``.  Per backend, a ridge regression regularized
-    toward the platform prior (bench runs yield few rows against 7
-    unknowns, so the prior anchors the underdetermined directions), with
-    coefficients clipped nonnegative — a negative seconds-per-op weight is
-    always noise.  Backends with no samples keep their prior.
-    """
-    base = default_rollout_cost_model(platform)
-    coeffs = dict(base.coeffs)
-    by_backend: dict = {}
-    for backend, feats, seconds in samples:
-        by_backend.setdefault(backend, []).append((feats, float(seconds)))
-    n_coef = len(ROLLOUT_FEATURES) + 1
-    for backend, rows in by_backend.items():
-        a = np.array([[f[k] for k in ROLLOUT_FEATURES] + [1.0]
-                      for f, _s in rows], np.float64)
-        y = np.array([s for _f, s in rows], np.float64)
-        scale = np.abs(a).max(axis=0)
-        scale[scale == 0] = 1.0
-        an = a / scale
-        c0 = np.asarray(base.coeffs.get(backend,
-                                        np.zeros(n_coef))) * scale
-        lam = 1e-2
-        lhs = an.T @ an + lam * np.eye(n_coef)
-        rhs = an.T @ y + lam * c0
-        c = np.linalg.solve(lhs, rhs) / scale
-        coeffs[backend] = np.maximum(c, 0.0)
-    return RolloutCostModel(coeffs=coeffs, platform=platform)
 
 
 # --- TPU analogue: what the technique buys on a memory-bound decode --------

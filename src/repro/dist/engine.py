@@ -53,7 +53,7 @@ class ShardedReservoirEngine(ReservoirEngine):
                  vmem_budget: int | None = _UNSET,
                  specialize: bool = True, tenant=None,
                  crossover: int | None = None,
-                 batch_tile_max: int | None = None, schedule=None):
+                 batch_tile_max: int | None = None):
         self.mesh = mesh if mesh is not None else make_data_mesh(n_shards)
         assert data_axis_names(self.mesh), \
             f"mesh has no data axes: {self.mesh.axis_names}"
@@ -65,15 +65,15 @@ class ShardedReservoirEngine(ReservoirEngine):
         # kept for elastic rebuilds: shrink() must reconstruct the engine
         # with the same dispatch policy, not the default
         self.dense_dispatch_density = dense_dispatch_density
-        # backend="auto" resolves through the plan autotuner in the base
-        # constructor — the per-shard program IS the single-device program
-        # (shard_map wraps _local_rollout), so the sharded engine inherits
-        # the tuned schedule for free.
+        # backend="auto" takes the plan's schedule in the base constructor
+        # — the per-shard program IS the single-device program (shard_map
+        # wraps _local_rollout), so the sharded engine runs the schedule
+        # the single-device engine would.
         super().__init__(params, backend=backend, stats=stats,
                          dense_dispatch_density=dense_dispatch_density,
                          vmem_budget=vmem_budget, specialize=specialize,
                          tenant=tenant, crossover=crossover,
-                         batch_tile_max=batch_tile_max, schedule=schedule)
+                         batch_tile_max=batch_tile_max)
         self._sharded_fns: dict = {}
 
     def like(self, params=None, *, mesh=None, stats=None, tenant=None):
@@ -84,21 +84,19 @@ class ShardedReservoirEngine(ReservoirEngine):
         but for X" — mesh-mapped engines are built per server, not
         through the global ``engine_for`` LRU, because the mesh is part
         of their identity.  Same params carry this engine's resolved
-        schedule verbatim; new params re-resolve through the tuner (a
-        different matrix has its own schedule space), inheriting the
-        tuned-ness rather than this matrix's tuned values."""
+        knobs verbatim; new params take the schedule the rule picks for
+        their own plan, not this matrix's values."""
         same = params is None or params is self.params
         return ShardedReservoirEngine(
             self.params if params is None else params,
             mesh=self.mesh if mesh is None else mesh,
-            backend=self.backend if same else self.requested_backend,
+            backend=self.requested_backend,
             stats=self.stats if stats is None else stats,
             dense_dispatch_density=self.dense_dispatch_density,
             vmem_budget=self.vmem_budget if same else _UNSET,
             specialize=self.specialize, tenant=tenant,
             crossover=self.crossover if same else None,
-            batch_tile_max=self.batch_tile_max if same else None,
-            schedule=self.schedule if same else None)
+            batch_tile_max=self.batch_tile_max if same else None)
 
     def _sharded(self, with_readout: bool, with_final: bool,
                  donate: bool = False):

@@ -112,42 +112,6 @@ def analytic_hbm_bytes(cfg: ModelConfig, shape: ShapeSpec, n_total: int,
 
 
 # ---------------------------------------------------------------------------
-# rollout roofline: the specialized reservoir rollout on the same machine
-# ---------------------------------------------------------------------------
-def rollout_roofline(summary: dict, block: int, batch: int,
-                     steps: int = 1) -> dict:
-    """Roofline view of one specialized rollout schedule on the TPU-v5e
-    anchor above: compute (folded-tile MACs on the MXU + digit adds on the
-    VPU) against memory (the weight stream the regime implies — once if
-    resident, per step if pipelined).  The plan autotuner uses this view
-    for reporting; its pruning uses the calibrated linear model in
-    :mod:`repro.core.costmodel`, which this shares its feature extraction
-    with so the two can never disagree about what a schedule *does*.
-    """
-    from repro.core.costmodel import rollout_cost_features
-    f = rollout_cost_features(summary, block, batch, steps)
-    # one MAC = 2 FLOPs on the MXU; digit adds run on the VPU at roughly
-    # 1/64 of MXU throughput (8x128 lanes vs the 128x128 systolic array)
-    t_c = 2.0 * f["matmul_macs"] / PEAK_FLOPS \
-        + f["shiftadd_ops"] / (PEAK_FLOPS / 64.0)
-    t_m = f["stream_bytes"] / HBM_BW
-    terms = {"compute": t_c, "memory": t_m}
-    dom = max(terms, key=terms.get)
-    if dom == "memory" and summary["regime"] == "pipelined":
-        advice = ("pipelined bands re-stream the folded tiles every step: "
-                  "raise the VMEM budget toward resident, or lower the "
-                  "crossover so more planes strength-reduce to shift-adds")
-    elif dom == "memory":
-        advice = ("weight fetch dominates even resident: fewer steps "
-                  "amortize the one-time hoist, or drop fp32 tiles to int8")
-    else:
-        advice = ("compute-bound: good; next lever is the shift-add "
-                  "crossover (trade MXU passes against VPU adds)")
-    return {"compute_s": t_c, "memory_s": t_m, "dominant": dom,
-            "bound_s": max(terms.values()), "advice": advice}
-
-
-# ---------------------------------------------------------------------------
 # assembly
 # ---------------------------------------------------------------------------
 def _advice(dom: str, cfg: ModelConfig, shape: ShapeSpec) -> str:

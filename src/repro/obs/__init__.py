@@ -12,8 +12,8 @@ goodput.  The three sinks:
   mergeable fixed-bucket latency histograms (exact p50/p99/p999 from
   bucket counts), exported as Prometheus text or JSON;
 * :class:`~repro.obs.trace.Tracer` — structured spans (request lifecycle
-  on the server clock; the scheduler's step phases, engine dispatch, plan
-  and autotune stages on the wall clock, each under the span that caused
+  on the server clock; the scheduler's step phases, engine dispatch and
+  plan stages on the wall clock, each under the span that caused
   it) in a bounded flight recorder with JSONL export;
 * :class:`~repro.obs.events.EventLog` — named, timestamped compile /
   retrace / cache-miss events, so an unexpected recompile under steady

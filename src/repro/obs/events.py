@@ -2,12 +2,12 @@
 
 Steady-state serving must never compile: the batcher owns one static pool
 shape, the engine caches its jitted rollouts per (shape, outputs, regime),
-and ``engine_for`` / ``plan_for`` / the autotune :class:`ScheduleCache`
-all memoize their expensive steps.  When that property breaks — a shape
-leaks through admission, a cache key regresses, a republish misses the
-prewarm — the only symptom used to be a mysterious latency spike.  This
-log turns it into evidence: the instrumented trace-counter and cache-miss
-sites emit an :class:`Event` (``kind`` plus free-form fields), and the
+and ``engine_for`` / ``plan_for`` memoize their expensive steps.  When that
+property breaks — a shape leaks through admission, a cache key regresses,
+a republish misses the prewarm — the only symptom used to be a mysterious
+latency spike.  This log turns it into evidence: the instrumented
+trace-counter and cache-miss sites emit an :class:`Event` (``kind`` plus
+free-form fields), and the
 ``retrace`` kind specifically marks a *re*-trace of an already-compiled
 program — the thing that must count zero under steady traffic (the
 ``serve_obs`` benchmark gates exactly that).
@@ -21,8 +21,6 @@ Well-known kinds emitted by the instrumented sites:
 ``engine_build``      a ReservoirEngine constructed (compile work follows)
 ``engine_cache_miss`` ``engine_for`` built instead of reusing
 ``plan_lowering``     ``plan_for`` lowered a matrix (cache miss)
-``schedule_resolve``  autotuner resolved a schedule (source: cache /
-                      predicted / measured)
 ``publish``           registry live swap executed
 ``shrink``            elastic reshard executed
 ====================  ======================================================

@@ -2,11 +2,11 @@
 
 One :class:`Tracer` holds a fixed-capacity ring of :class:`Span` records —
 enough history to reconstruct *why* the last N requests were slow (queue
-wait vs. chunk stall vs. an autotune recompile) without growing without
-bound under sustained traffic.  Spans carry:
+wait vs. chunk stall vs. a recompile) without growing without bound
+under sustained traffic.  Spans carry:
 
 * ``name``      — the stage (``request.queued``, ``scheduler.step``,
-  ``engine.dispatch``, ``autotune.trial``, ...);
+  ``engine.dispatch``, ``plan.specialize``, ...);
 * ``parent``    — the name of the wall-clock span open around it when it
   was recorded (``scheduler.admit`` sits under ``scheduler.step``), so a
   phase's self time is its duration less its children's;

@@ -5,26 +5,10 @@ every static artifact the kernels, the serve engine and the cost reports
 consume: gathered nonzero tiles, per-column reduction term lists (with
 block- and plane-level culling), whole-plane masks, VMEM-banded rollout
 layouts, the sorted BCSR tile list, and the FPGA cost model attached to
-the exact decomposed structure.  :mod:`repro.plan.autotune` closes the
-loop: it searches the specialization's schedule space (regime, crossover,
-band budget, batch tile, backend) with a calibrated cost model plus
-measured-cost feedback, and caches the winner per (plan, hardware).
+the exact decomposed structure.  :mod:`repro.plan.schedule` picks the
+lowering a plan runs in from the plan and the platform.
 """
 
-from repro.plan.autotune import (
-    Schedule,
-    ScheduleCache,
-    TunedSchedule,
-    autotune_cache,
-    autotune_cache_load,
-    autotune_cache_save,
-    autotune_rollout,
-    candidate_schedules,
-    default_schedule,
-    plan_fingerprint,
-    resolve_backend,
-    resolve_schedule,
-)
 from repro.plan.plan import (
     DEFAULT_VMEM_BUDGET,
     BandedRollout,
@@ -35,6 +19,7 @@ from repro.plan.plan import (
     plan_cache_stats,
     plan_for,
 )
+from repro.plan.schedule import Schedule, choose_schedule
 from repro.plan.specialize import (
     DEFAULT_BATCH_TILE,
     RolloutProgram,
@@ -52,19 +37,9 @@ __all__ = [
     "RolloutBand",
     "RolloutProgram",
     "Schedule",
-    "ScheduleCache",
-    "TunedSchedule",
-    "autotune_cache",
-    "autotune_cache_load",
-    "autotune_cache_save",
-    "autotune_rollout",
-    "candidate_schedules",
-    "default_schedule",
+    "choose_schedule",
     "plan_cache_stats",
-    "plan_fingerprint",
     "plan_for",
-    "resolve_backend",
-    "resolve_schedule",
     "specialize_rollout",
     "specialize_summary",
 ]

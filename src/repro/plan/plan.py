@@ -432,15 +432,7 @@ class ExecutionPlan:
 
     def describe(self, input_bits: int = 8,
                  vmem_budget: int | None = DEFAULT_VMEM_BUDGET) -> str:
-        """Human-readable compile summary: structure kept/culled + FPGA cost.
-
-        When the autotuner has resolved a schedule for this plan
-        (:func:`repro.plan.autotune.resolve_schedule` — every
-        ``backend="auto"`` engine construction does), one ``autotuned``
-        line per tuning decision reports the chosen backend / band budget /
-        crossover / batch tile and the predicted vs measured rollout cost
-        behind it.
-        """
+        """Human-readable compile summary: structure kept/culled + FPGA cost."""
         s = self.stats
         dp = self.fpga_cost(input_bits)
         # partition only — cost summaries must not pay for the tile gather
@@ -464,10 +456,6 @@ class ExecutionPlan:
             f"  Eq.5 latency: {dp.cycles} cycles = {dp.latency_ns:.1f} ns  "
             f"power = {dp.power_w:.1f} W",
         ]
-        for (mode, bucket, hw), tuned in sorted(
-                getattr(self, "_tuned", {}).items(), key=repr):
-            lines.append(f"  autotuned[{mode} b<={bucket} {hw}]: "
-                         + tuned.describe())
         return "\n".join(lines)
 
 

@@ -346,8 +346,8 @@ def _partition(plan: ExecutionPlan, col_mm_counts: np.ndarray,
 def _lowerings(plan: ExecutionPlan, mode: str, crossover: int):
     """Column lowerings cached per ``(mode, crossover)`` on the plan — the
     expensive half of the analysis (digit-plane folding) is independent of
-    the band budget and batch tile, so the autotuner prices its whole
-    budget x tile candidate grid off one fold per crossover."""
+    the band budget and batch tile, so every budget and tile of one
+    crossover shares one fold."""
     cache = getattr(plan, "_lowerings", None)
     if cache is None:
         cache = plan._lowerings = {}
@@ -418,12 +418,13 @@ def specialize_summary(plan: ExecutionPlan, mode: str = "fp32",
                        crossover: int | None = None,
                        batch_tile_max: int = DEFAULT_BATCH_TILE) -> dict:
     """Counts-level view of the specialization — what ``describe`` reports
-    and what the autotuner prices candidates from.
+    and what :func:`~repro.plan.schedule.choose_schedule` reads the
+    resident bytes from.
 
     Keyed on the FULL schedule tuple ``(mode, vmem_budget, crossover,
     batch_tile_max)`` — the same key :func:`specialize_rollout` caches
-    programs under, so tuned variants that differ only in batch tiling
-    never collide.  Reads the fields off an already-cached
+    programs under, so schedules that differ only in batch tiling never
+    collide.  Reads the fields off an already-cached
     :class:`RolloutProgram` when one exists for exactly these parameters;
     otherwise runs the shared analysis once — never materializing the
     banded data array — and caches the result on the plan.  Always

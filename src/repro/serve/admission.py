@@ -12,8 +12,8 @@ promise.  This module closes the gap with a pluggable
 * :class:`DeadlineShedPolicy` — shed a request whose deadline the
   *estimated* queue delay already blows, so it never burns a slot (or a
   queue position) on an answer nobody will wait for.  The delay estimate
-  reuses the PR-7 calibrated cost model's per-chunk prediction when the
-  server has no measured chunk cost yet;
+  prices each chunk at the server's measured chunk cost, or at a small
+  constant before any chunk has run;
 * :class:`TenantFairnessPolicy` — weighted per-tenant share of the
   in-system work, on top of the registry's concurrency quota (quota
   bounds *seated* slots; fairness bounds a tenant's claim on the whole
@@ -31,6 +31,9 @@ from __future__ import annotations
 
 import dataclasses
 import math
+
+# Per-chunk seconds assumed before a server has measured any chunk
+COLD_CHUNK_SECONDS = 1e-3
 
 
 @dataclasses.dataclass(frozen=True)
@@ -69,33 +72,15 @@ def estimate_chunk_seconds(server) -> float:
 
     Preference order: the fixed virtual-clock ``chunk_time`` when set
     (it *is* the chunk cost by definition), the measured per-call EWMA
-    once chunks have run, then the PR-7 calibrated cost model's analytic
-    prediction for the pool shape (``n_slots`` x ``chunk_steps`` under
-    the engine's resolved schedule) — so admission decisions are
-    cost-aware from the very first submit, before anything has been
-    measured.
+    once chunks have run, else :data:`COLD_CHUNK_SECONDS` before anything
+    has been measured, so policies stay functional from the first submit.
     """
     if server.chunk_time is not None:
         return float(server.chunk_time)
     st = server.stats
     if st.chunks and st.latency_ewma_s > 0:
         return float(st.latency_ewma_s)
-    eng = server.batcher.engine
-    try:
-        from repro.plan.autotune import Schedule, predict_cost
-        sched = eng.schedule
-        if sched is None:
-            sched = Schedule(
-                "int8" if eng.config.mode.startswith("int8") else "fp32",
-                eng.backend, eng.vmem_budget, eng.crossover,
-                eng.batch_tile_max)
-        est = predict_cost(eng.plan, sched, server.batcher.n_slots,
-                           server.batcher.chunk_steps)
-        return max(float(est), 1e-6)
-    except Exception:
-        # cost model unavailable for this engine/backend combination:
-        # fall back to a small constant so policies stay functional
-        return 1e-3
+    return COLD_CHUNK_SECONDS
 
 
 def estimate_queue_delay(server) -> float:
@@ -231,7 +216,7 @@ def default_policy(*, max_depth: int = 64,
         TenantFairnessPolicy(weights=weights or {}))
 
 
-__all__ = ["Rejection", "AdmissionPolicy", "BoundedQueuePolicy",
-           "DeadlineShedPolicy", "TenantFairnessPolicy", "CompositePolicy",
-           "default_policy", "estimate_chunk_seconds",
+__all__ = ["COLD_CHUNK_SECONDS", "Rejection", "AdmissionPolicy",
+           "BoundedQueuePolicy", "DeadlineShedPolicy", "TenantFairnessPolicy",
+           "CompositePolicy", "default_policy", "estimate_chunk_seconds",
            "estimate_queue_delay"]

@@ -10,17 +10,23 @@ compile-to-bitstream step) and fronts two fused implementations:
 * ``xla``    — a jitted ``lax.scan`` whose body does the *batched*
   recurrent multiply natively (dense or block-culled, dispatched on the
   plan's structure) with the input projection hoisted into a single
-  (B*T, I) x (I, R) gemm before the scan.  The fast path on CPU/GPU.
+  (B*T, I) x (I, R) gemm before the scan.
 * ``pallas`` — the ``reservoir_rollout`` Pallas kernel fed by the plan's
   VMEM-banded layout: T steps fused in one launch, state resident in VMEM,
-  one band of weight tiles streamed per grid step.  The TPU path: compiled
-  on the TPU, run by the Pallas interpreter on the CPU — the platform
-  decides (:mod:`repro.kernels.platform`), never a caller option.
+  one band of weight tiles streamed per grid step.  Compiled on the TPU,
+  run by the Pallas interpreter on the CPU — the platform decides
+  (:mod:`repro.kernels.platform`), never a caller option.
 
-With a trained readout the engine serves *predictions*: ``W_out`` is fused
-into the rollout epilogue (per-step ``y = x @ W_out`` inside the scan body
-/ Pallas launch), so the state trajectory is never materialized on the
-prediction path.  The request/response surface is the unified
+``backend="auto"`` takes the backend :func:`repro.plan.choose_schedule`
+picks from the plan and the platform: Pallas on the TPU where the folded
+tiles fit VMEM, else XLA.
+
+With a trained readout the engine serves *predictions*: ``W_out`` is
+applied inside the one compiled rollout, so only predictions leave the
+device.  The Pallas kernel applies it per step inside the launch and
+never materializes the state trajectory; XLA's scan stacks the (B, T, R)
+trajectory and applies ``W_out`` after the scan.  The request/response
+surface is the unified
 :class:`~repro.serve.api.SubmitSpec` -> :class:`~repro.serve.api.RolloutResult`
 contract (``submit`` / ``submit_many``); ``want_states=True`` on the spec
 keeps the states contract, and the chunked schedulers drive
@@ -43,9 +49,8 @@ from repro import obs
 from repro.core.esn import ESNParams
 from repro.kernels.reservoir_rollout.ops import FusedRollout
 from repro.kernels.reservoir_rollout.specialized import SpecializedRollout
-from repro.plan import (DEFAULT_BATCH_TILE, DEFAULT_VMEM_BUDGET, plan_for,
-                        specialize_rollout)
-from repro.plan.autotune import resolve_backend, resolve_schedule
+from repro.plan import (DEFAULT_BATCH_TILE, DEFAULT_VMEM_BUDGET,
+                        choose_schedule, plan_for, specialize_rollout)
 from repro.plan.specialize import int8_recur_reference, specialize_summary
 from repro.serve.api import (_UNSET, RolloutResult, SubmitSpec,
                              lifecycle_timings, warn_deprecated)
@@ -93,7 +98,7 @@ class ReservoirEngine:
                  vmem_budget: int | None = _UNSET,
                  specialize: bool = True, tenant: str | None = None,
                  crossover: int | None = None,
-                 batch_tile_max: int | None = None, schedule=None):
+                 batch_tile_max: int | None = None):
         assert backend in ("auto", "xla", "pallas"), backend
         self.params = params
         self.config = params.config
@@ -111,30 +116,24 @@ class ReservoirEngine:
                 f"mode {self.config.mode!r} takes state_bits <= 8 (the "
                 f"requantized state is an int8 operand), got "
                 f"state_bits={self.config.state_bits}")
-        # backend="auto" resolves through the plan autotuner: a persisted
-        # tuning cache replays the measured winner, a cold cache falls
-        # back to the analytic cost model's pick — never a hardcoded
-        # backend.  The tuned schedule fills every knob the caller left
-        # unset; explicit kwargs always win (a caller pinning the budget
-        # keeps it).  ``schedule`` accepts a Schedule or TunedSchedule to
-        # bypass resolution entirely (the bench harness injects measured
-        # winners this way).
+        # backend="auto" takes the schedule the plan and the platform
+        # decide (repro.plan.schedule): it fills every knob the caller left
+        # unset, and explicit kwargs win (a caller pinning the budget
+        # keeps it).
         self.requested_backend = backend
-        if schedule is None and backend == "auto" and specialize:
-            schedule = resolve_schedule(
-                self.plan, "int8" if self._int8 else "fp32")
-        sched = getattr(schedule, "schedule", schedule)
-        self.schedule = sched
-        if sched is not None:
-            self.backend = sched.backend if backend == "auto" else backend
+        self.schedule = None
+        if backend == "auto" and specialize:
+            sched = self.schedule = choose_schedule(
+                self.plan, "int8" if self._int8 else "fp32",
+                jax.default_backend())
+            backend = sched.backend
             if vmem_budget is _UNSET:
                 vmem_budget = sched.vmem_budget
             if crossover is None:
                 crossover = sched.crossover
             if batch_tile_max is None:
                 batch_tile_max = sched.batch_tile_max
-        else:
-            self.backend = "xla" if backend == "auto" else backend
+        self.backend = "xla" if backend == "auto" else backend
         self.vmem_budget = DEFAULT_VMEM_BUDGET if vmem_budget is _UNSET \
             else vmem_budget
         self.crossover = crossover
@@ -786,21 +785,19 @@ def engine_for(params: ESNParams, backend: str = "auto", *,
     evicted or ``engine_cache_clear()`` runs — the cache trades bounded
     pinning for compile reuse.
 
-    ``backend="auto"`` keys the cache on the backend the plan autotuner
-    resolves for these params — the SAME resolution the constructor runs,
-    so the cache key and the built engine's backend always agree (resolution
-    is deterministic and cached on the plan; it used to be hardcoded
-    ``"xla"`` for the key while the constructor got the raw string).
+    ``backend="auto"`` keys the cache on the backend
+    :func:`~repro.plan.schedule.choose_schedule` picks for these params,
+    the rule the constructor runs, so the cache key and the built engine's
+    backend always agree.
     """
     if backend != "auto":
         bk = backend
-    elif kwargs.get("schedule") is not None:
-        sched = kwargs["schedule"]
-        bk = getattr(sched, "schedule", sched).backend
     elif not kwargs.get("specialize", True):
-        bk = "xla"  # unspecialized engines have no schedule space to tune
+        bk = "xla"  # unspecialized engines take no schedule
     else:
-        bk = resolve_backend(params, backend)
+        mode = "int8" if params.config.mode.startswith("int8") else "fp32"
+        bk = choose_schedule(plan_for(params.w), mode,
+                             jax.default_backend()).backend
     if tenant is None:
         key = (id(params), bk)
         ent = _engine_cache.get(key)

@@ -29,7 +29,8 @@ from repro.serve import (AsyncReservoirServer, BoundedQueuePolicy,
                          CompositePolicy, DeadlineShedPolicy, ModelRegistry,
                          ReservoirEngine, Rejection, ServeStats, SubmitSpec,
                          TenantFairnessPolicy, default_policy)
-from repro.serve.admission import (estimate_chunk_seconds,
+from repro.serve.admission import (COLD_CHUNK_SECONDS,
+                                   estimate_chunk_seconds,
                                    estimate_queue_delay)
 
 
@@ -90,13 +91,13 @@ class TestEstimators:
         assert estimate_chunk_seconds(srv) == 1.0
 
     def test_cost_model_used_before_any_measurement(self):
-        # chunk_time=None and no chunks run yet: the PR-7 cost model's
-        # analytic prediction kicks in (positive, finite) so admission
-        # is cost-aware from the first submit
+        # chunk_time=None and no chunks run yet: the cold constant answers
+        # (positive, finite) so policies work from the first submit
         eng, srv = _server(_params(), n_slots=2, chunk_steps=8,
                            chunk_time=None)
         assert srv.stats.chunks == 0
         est = estimate_chunk_seconds(srv)
+        assert est == COLD_CHUNK_SECONDS
         assert 0 < est < float("inf")
 
     def test_queue_delay_zero_when_idle(self):

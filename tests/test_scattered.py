@@ -7,7 +7,7 @@ program has no folded tile.  The XLA backend's culled schedule reads the
 whole matrix from ELL tables in one gather-multiply-accumulate: exact in
 int32, and a program whose size is fixed by the table's shape, not by
 the matrix's nonzeros or digits.  Where the gather is dear (the TPU), the
-autotuner picks the folded dense product of the same integers instead.
+schedule rule picks the folded dense product of the same integers instead.
 """
 
 import jax
@@ -19,14 +19,12 @@ from hypothesis import strategies as st
 
 from repro import obs
 from repro.configs.esn_paper import LARGE_1024
-from repro.core import costmodel
 from repro.core.esn import ESNConfig, ESNParams, init_esn
 from repro.core.sparse import FixedMatrix
-from repro.plan import plan_for, specialize_rollout, specialize_summary
-from repro.plan.autotune import (ScheduleCache, candidate_schedules,
-                                 predict_cost, resolve_schedule)
-from repro.plan.specialize import (int8_recur_reference, scattered_table,
-                                   table_product)
+from repro.plan import (choose_schedule, plan_for, specialize_rollout,
+                        specialize_summary)
+from repro.plan.specialize import (default_crossover, int8_recur_reference,
+                                   scattered_table, table_product)
 from repro.serve import AsyncReservoirServer, ReservoirEngine, SubmitSpec
 
 IN, OUT = 20, 8           # 8 grid points plus 6 on each side in, 8 out
@@ -159,8 +157,9 @@ class TestTable:
         assert digits[1] > 1.5 * digits[0]
 
     def test_served_path_matches_plain_reference(self):
-        """Through ``AsyncReservoirServer`` on the default (autotuned)
-        engine, against a plain ``jax.numpy`` rollout at the highest matmul
+        """Through ``AsyncReservoirServer`` on an engine of the default
+        backend at the block's default crossover (so the table serves),
+        against a plain ``jax.numpy`` rollout at the highest matmul
         precision.
 
         Tolerances: the recurrent product is exact integers on both sides
@@ -175,7 +174,7 @@ class TestTable:
         dim = 640
         fm = _fm(dim)
         p = _params(fm, seed=3)
-        eng = ReservoirEngine(p)
+        eng = ReservoirEngine(p, crossover=default_crossover(128))
         assert eng.backend == "xla" and eng.xla_schedule == "int8-folded-culled"
         assert eng._int8_summary()["kind"] == "scattered"
         rng = np.random.default_rng(4)
@@ -226,64 +225,52 @@ class TestTable:
 class TestSelection:
     def test_existing_cell_keeps_its_program(self):
         """The benchmark's dim-1024 95%-sparse matrix: 64 folded tiles,
-        resident, no shift-add term, no table; the TPU cold pick stays the
+        resident, no shift-add term, no table; the TPU pick stays the
         Pallas kernel at crossover 0."""
         plan = plan_for(init_esn(LARGE_1024).w)
         program = specialize_rollout(plan, "int8")
         assert program.regime == "resident" and program.kind == "tiles"
         assert program.n_matmul_terms == 64
         assert program.n_shiftadd_terms == 0 and program.table is None
-        tpu = costmodel.default_rollout_cost_model("tpu")
-        pick = resolve_schedule(plan, "int8", model=tpu,
-                                cache=ScheduleCache()).schedule
+        pick = choose_schedule(plan, "int8", "tpu")
         assert (pick.backend, pick.crossover) == ("pallas", 0)
 
     def test_pallas_unroll_beyond_vmem_is_no_candidate(self):
         """The Pallas kernel keeps one (b_tile, 1) column per output lane
-        of its shift-add unroll in VMEM.  At 3072 nodes and degree 3 no
-        batch tile leaves that under the budget, so no Pallas candidate
-        keeps a shift-add term (its folded-tile programs stay); at 640
-        nodes the unroll fits."""
-        def pallas_digits(dim):
+        of its shift-add unroll in VMEM, which at 3072 nodes and degree 3
+        no batch tile keeps under the budget.  The TPU pick never unrolls:
+        at 3072 nodes the folded tiles overflow the budget too, so it is
+        XLA's fold; at 640 it is the Pallas kernel at crossover 0, all
+        folded tiles, where the default crossover would unroll digits."""
+        def pick_and_digits(dim):
             plan = plan_for(_degree3(dim, seed=1))
-            return [specialize_summary(
-                plan, "int8", vmem_budget=s.vmem_budget,
-                crossover=s.crossover, batch_tile_max=s.batch_tile_max)
-                ["shiftadd_digits"]
-                for s in candidate_schedules(plan, "int8")
-                if s.backend == "pallas"]
+            pick = choose_schedule(plan, "int8", "tpu")
+            return pick, [specialize_summary(
+                plan, "int8", vmem_budget=None, crossover=c,
+                batch_tile_max=pick.batch_tile_max)["shiftadd_digits"]
+                for c in (pick.crossover, default_crossover(plan.block))]
 
-        big = pallas_digits(3072)
-        assert big and not any(big)
-        assert any(pallas_digits(640))
+        big, (big_digits, _) = pick_and_digits(3072)
+        assert big.backend == "xla" and big_digits == 0
+        small, (small_digits, unrolled) = pick_and_digits(640)
+        assert (small.backend, small.crossover) == ("pallas", 0)
+        assert small_digits == 0 and unrolled > 0
 
     def test_cold_pick_is_the_fastest_measured(self):
         """A degree-3 reservoir whose folded tiles overflow a kernel's
-        VMEM.  On the TPU the cold pick is XLA's dense fold: the Pallas
-        shift-add unroll is no candidate (its VMEM), and the table and the
-        Pallas pipelined fold are priced above the fold at 8, 64 and 256
-        rows.  On a v5e chip the 5000-node cell's fold took 0.64 / 0.67 /
-        0.80 ms per 16-step launch, its table 1.77 / 1.80 / 3.26 ms, and
-        the Pallas kernel multiplies folded tiles slower than XLA.  On the
-        CPU, where the gather is cheap, the pick reads the table."""
+        VMEM.  On the TPU the pick is XLA's dense fold, the fastest
+        lowering a v5e chip has measured for such a matrix: the 5000-node
+        cell's fold took 0.64 / 0.67 / 0.80 ms per 16-step launch of 8 /
+        64 / 256 rows, its scattered table 1.77 / 1.80 / 3.26 ms, and the
+        Pallas kernel multiplies folded tiles slower than XLA.  On the
+        CPU, where Pallas runs interpreted, the pick is XLA's fold too;
+        the table serves only where a caller asks for a crossover."""
         plan = plan_for(_degree3(3072, seed=1))
-        tpu = costmodel.default_rollout_cost_model("tpu")
-        pick = resolve_schedule(plan, "int8", model=tpu,
-                                cache=ScheduleCache()).schedule
-        assert (pick.backend, pick.crossover) == ("xla", 0)
+        for platform in ("tpu", "cpu"):
+            pick = choose_schedule(plan, "int8", platform)
+            assert (pick.backend, pick.crossover) == ("xla", 0)
         assert specialize_summary(plan, "int8", crossover=0)["kind"] \
             == "tiles"
-        others = [s for s in candidate_schedules(plan, "int8")
-                  if s.backend == "pallas" or s.crossover > 0]
-        assert {specialize_summary(plan, "int8", crossover=s.crossover)
-                ["kind"] for s in others} == {"tiles", "scattered"}
-        for rows in (8, 64, 256):
-            fold = predict_cost(plan, pick, rows, 16, tpu)
-            assert min(predict_cost(plan, s, rows, 16, tpu)
-                       for s in others) > fold
-        cpu = costmodel.default_rollout_cost_model("cpu")
-        cpu_pick = resolve_schedule(plan, "int8", model=cpu,
-                                    cache=ScheduleCache()).schedule
-        assert cpu_pick.backend == "xla"
-        assert specialize_summary(plan, "int8", crossover=cpu_pick.crossover
-                                  )["kind"] == "scattered"
+        assert specialize_summary(
+            plan, "int8", crossover=default_crossover(plan.block)
+        )["kind"] == "scattered"

@@ -225,7 +225,7 @@ REGIMES = ("resident", "pipelined")
 class TestSpecializedParity:
     # batch >= 2: a one-row fp32 batch may lower the recurrent matmul as a
     # gemv whose accumulation order differs by an ulp between the two
-    # kernels (the documented one-row caveat, as in test_autotune.py)
+    # kernels (the documented one-row caveat, as in test_schedule.py)
     @given(st.sampled_from(MODES), st.sampled_from(REGIMES),
            st.booleans(), st.integers(2, 20), st.integers(0, 10**6))
     @settings(max_examples=15, deadline=None)
